@@ -876,7 +876,6 @@ def _bench_fleet(results):
     plan = interpreter.compile_plan(prog)
     oracle = np.asarray(jax.jit(
         lambda pk, im: plan.forward(pk, im)[1])(art, jnp.asarray(frames)))
-    warm_dir = warmcache.enable_persistent()   # CI uploads the directory
 
     def bring_up():
         t0 = time.perf_counter()
@@ -925,7 +924,6 @@ def _bench_fleet(results):
     results["fleet_migrated_frames"] = st.migrated_frames
     results["fleet_refired_frames"] = st.refired_frames
     results["fleet_uj_per_frame"] = round(st.chip.uj_per_frame, 3)
-    results["warm_cache_dir"] = warm_dir
     return ok
 
 
@@ -1051,4 +1049,6 @@ def run(csv: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.kernels import cache as warmcache
+    warmcache.enable_persistent()
     raise SystemExit(0 if run() else 1)

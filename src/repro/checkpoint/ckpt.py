@@ -51,15 +51,12 @@ def save(path: str, state: Any, step: Optional[int] = None) -> None:
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-portable mesh constructor for the restore-after-fault path.
+    """Mesh constructor for the restore-after-fault path.
 
     A job restarted after a fault rebuilds its mesh on whatever topology
-    survived and restores the latest checkpoint onto it.  ``jax.make_mesh``
-    grew an ``axis_types`` kwarg (and ``jax.sharding.AxisType``) only in
-    newer JAX releases; restore code that reached for those crashed the
-    recovery itself on older runtimes.  This helper uses only the Mesh
-    constructor every supported version has, so rebuilding the mesh can
-    never be the step that kills a restart.
+    survived and restores the latest checkpoint onto it: the first
+    ``prod(axis_shapes)`` of ``devices`` (default: all local devices), in
+    order, with Auto axis types.
     """
     devices = list(jax.devices()) if devices is None else list(devices)
     n = 1

@@ -71,29 +71,27 @@ def pack_signs(x: jax.Array, axis: int = -1) -> jax.Array:
         pad = [(0, 0)] * x.ndim
         pad[axis] = (0, kp - k)
         x = jnp.pad(x, pad, constant_values=1.0)  # +1 -> bit 0
-    # move pack axis last
-    x = jnp.moveaxis(x, axis, -1)
-    bits = (x < 0).astype(_PACK_DTYPE)  # -1 -> 1
-    bits = bits.reshape(x.shape[:-1] + (kp // PACK_WIDTH, PACK_WIDTH))
-    shifts = jnp.arange(PACK_WIDTH, dtype=_PACK_DTYPE)
-    words = jnp.sum(bits << shifts, axis=-1, dtype=_PACK_DTYPE)
+    # move pack axis last; -1 -> bit 1
+    words = pack_bit_lanes(jnp.moveaxis(x, axis, -1) < 0)
     return jnp.moveaxis(words, -1, axis)
 
 
 def pack_bit_lanes(bits: jax.Array) -> jax.Array:
     """Pack a (..., K) array of {0,1} sign bits into (..., K//32) uint32.
 
-    The shared packing idiom for code that already *has* sign bits
-    (Pallas kernel bodies, the packed thermometer encoder) — same
-    LSB-first lane order as :func:`pack_signs`, which handles the
-    +/-1-float and padding cases.  K must be a multiple of 32.
+    The shared packing idiom for code that already *has* sign bits (the
+    packed thermometer encoder, :func:`pack_signs`) — LSB-first lane
+    order.  K must be a multiple of 32.  The distinct powers of two sum
+    without carries, so a signed sum (wrapping into bit 31) is the
+    bitwise OR; TPU compilers have no unsigned integer reduction.
     """
     k = bits.shape[-1]
     assert k % PACK_WIDTH == 0, k
-    lanes = bits.astype(_PACK_DTYPE).reshape(
+    lanes = bits.astype(jnp.int32).reshape(
         bits.shape[:-1] + (k // PACK_WIDTH, PACK_WIDTH))
-    shifts = jnp.arange(PACK_WIDTH, dtype=_PACK_DTYPE)
-    return jnp.sum(lanes << shifts, axis=-1, dtype=_PACK_DTYPE)
+    shifts = jnp.arange(PACK_WIDTH, dtype=jnp.int32)
+    words = jnp.sum(lanes << shifts, axis=-1, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(words, _PACK_DTYPE)
 
 
 def unpack_signs(words: jax.Array, k: int, axis: int = -1,
@@ -123,11 +121,12 @@ def thermometer_pack(images: jax.Array, bits: int, cin: int,
                      channels: int) -> jax.Array:
     """Thermometer-encode integer pixels straight into packed uint32 words.
 
-    The single source of truth for the chip's IO layer arithmetic, shared
-    by ``neuron_array.thermometer_encode_packed`` (the staged pipeline)
-    and the whole-network megakernel's in-kernel encode — one
-    implementation so the two execution modes cannot drift apart.  Plane
-    i of color c is -1 (bit 1) exactly when ``x_c < t_i``; leftover
+    The chip's IO layer arithmetic for the staged pipeline
+    (``neuron_array.thermometer_encode_packed``) and the host-side gate
+    references; the megakernel's in-kernel encode
+    (``megakernel.thermometer_lanes``) repeats the same float32 ops on
+    the kernel's lane layout, and the tests hold the two bit-exact.
+    Plane i of color c is -1 (bit 1) exactly when ``x_c < t_i``; leftover
     planes are constant +1 bias (bit 0).  ``channels`` must be a
     multiple of 32.  (..., H, W, cin) int -> (..., H, W, channels//32).
     """

@@ -393,11 +393,12 @@ class InferencePlan:
                                                       interpret=interpret)
         if mesh is not None and mesh.devices.size > 1:
             from jax.sharding import PartitionSpec as P
-            from repro.distributed import context as dctx
             axis = mesh.axis_names[0]
-            fwd = dctx.shard_map(fwd, mesh=mesh,
-                                 in_specs=(P(), P(axis)),
-                                 out_specs=(P(axis), P(axis)))
+            fwd = jax.shard_map(fwd, mesh=mesh,
+                                in_specs=(P(), P(axis)),
+                                out_specs=(P(axis), P(axis)),
+                                check_vma=False)   # Pallas outputs
+                                                   # carry no vma type
         donate = (1,) if donate_frames else ()
         return jax.jit(fwd, donate_argnums=donate)
 
@@ -551,11 +552,12 @@ class CompositePlan:
                                                  bb=bb, ft=ft)
         if mesh is not None and mesh.devices.size > 1:
             from jax.sharding import PartitionSpec as P
-            from repro.distributed import context as dctx
             axis = mesh.axis_names[0]
-            fwd = dctx.shard_map(fwd, mesh=mesh,
-                                 in_specs=(P(), P(axis)),
-                                 out_specs=(P(axis), P(axis)))
+            fwd = jax.shard_map(fwd, mesh=mesh,
+                                in_specs=(P(), P(axis)),
+                                out_specs=(P(axis), P(axis)),
+                                check_vma=False)   # Pallas outputs
+                                                   # carry no vma type
         donate = (1,) if donate_frames else ()
         return jax.jit(fwd, donate_argnums=donate)
 

@@ -34,35 +34,6 @@ def mesh_context(mesh: Optional[jax.sharding.Mesh]):
         _state.mesh = prev
 
 
-# ---------------------------------------------------------------------------
-# Version portability: shard_map / pcast moved surfaces across JAX releases
-# ---------------------------------------------------------------------------
-
-def shard_map(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where it exists, ``jax.experimental.shard_map``
-    otherwise.
-
-    The experimental form predates varying-manual-axes (vma) tracking, so
-    replication checking is disabled there — the newer surface checks vma
-    natively and the bodies used here (ppermute pipeline, all_to_all MoE)
-    mark their carries with :func:`pcast` when the runtime supports it.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def pcast(x, axes, to="varying"):
-    """``jax.lax.pcast`` on runtimes with vma tracking; identity before it
-    existed (older shard_map has no replication typing to satisfy)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x
-
-
 def data_axes(mesh: jax.sharding.Mesh) -> tuple:
     """All mesh axes that carry the batch (everything except 'model')."""
     return tuple(a for a in mesh.axis_names if a != "model")

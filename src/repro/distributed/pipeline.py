@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import context as dctx
 
 
 def pipelined(stage_fn: Callable, mesh, num_microbatches: int,
@@ -47,8 +46,8 @@ def pipelined(stage_fn: Callable, mesh, num_microbatches: int,
             n_ticks = num_microbatches + n_stages - 1
             # the carry becomes pod-varying after ppermute/axis_index; the
             # zero init must be marked pod-varying too (shard_map vma rule)
-            buf = dctx.pcast(jnp.zeros_like(mb[0]), (axis,), to="varying")
-            outs = dctx.pcast(jnp.zeros_like(mb), (axis,), to="varying")
+            buf = jax.lax.pcast(jnp.zeros_like(mb[0]), (axis,), to="varying")
+            outs = jax.lax.pcast(jnp.zeros_like(mb), (axis,), to="varying")
 
             def tick(carry, t):
                 buf, outs = carry
@@ -79,7 +78,7 @@ def pipelined(stage_fn: Callable, mesh, num_microbatches: int,
 
         in_specs = (jax.tree.map(lambda _: P(axis), stage_params),
                     P(other if other else None))
-        return dctx.shard_map(body, mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                              out_specs=P(other if other else None))(
                                  stage_params, x)
 

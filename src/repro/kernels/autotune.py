@@ -49,10 +49,10 @@ DEFAULT_CACHE = "BENCH_autotune.json"
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 SCHEMA = 2          # bump when tuned fields / kernel schedule change shape
 
-# the pre-autotuner defaults, kept as the documented cold-cache behaviour
+# the cold-cache defaults (staged bf=256: one F tile at every S-mode)
 DEFAULTS = {
     "mega": {"bb": 8, "ft": 0},
-    "staged_conv": {"bf": 64, "bb": 8},
+    "staged_conv": {"bf": 256, "bb": 8},
 }
 
 _cache: Optional[Dict[str, dict]] = None

@@ -25,13 +25,15 @@ schema-versioned-key discipline (:mod:`repro.kernels.autotune`):
   or kernel schedule changes shape, the version bumps and stale entries
   silently degrade to a cold build (never an error, never a wrong
   executable — a cache hit may only ever change *speed*).
-* **Persistent tier** — JAX's own compilation cache, pointed at a
-  directory (env ``REPRO_WARM_CACHE``, default ``BENCH_warm_cache``):
-  XLA executables are serialized per (computation fingerprint, device
-  kind, compiler version) by JAX itself, so a replica in a *new
-  process* also comes up hot.  CI uploads the directory as an artifact
-  next to ``BENCH_autotune.json``; enabling is best-effort — on a JAX
-  build without the config knobs it degrades to the process tier only.
+* **Persistent tier** — JAX's own compilation cache: XLA executables
+  are serialized per (computation fingerprint, device kind, compiler
+  version) by JAX itself, so a replica in a *new process* also comes up
+  hot.  :func:`enable_persistent` turns it on from the entry points
+  (``chip_smoke.py``, ``chip_serve``, the kernel microbench), never at
+  import.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and
+  no directory is set in code; otherwise the cache lives at one fixed,
+  gitignored path inside the checkout (:data:`DEFAULT_DIR`) — the path
+  is part of the cache key, so it never moves between runs.
 
 The in-process ledger (:func:`stats`) records hits/misses and the
 seconds spent building on misses — the bench derives its warm-start
@@ -51,8 +53,10 @@ from repro.core.chip import isa
 from repro.kernels import autotune
 
 SCHEMA = 1          # bump when serve-fn signatures / kernel schedule change
-CACHE_ENV = "REPRO_WARM_CACHE"
-DEFAULT_DIR = "BENCH_warm_cache"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"     # JAX's own variable
+# the checkout root is three levels above this package file
+DEFAULT_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_compilation_cache"))
 
 _fns: Dict[str, Any] = {}
 _stats = {"hits": 0, "misses": 0, "build_s": 0.0}
@@ -134,36 +138,30 @@ def invalidate() -> None:
 
 
 def cache_dir() -> str:
-    return os.environ.get(CACHE_ENV, DEFAULT_DIR)
+    """Where the persistent tier lives: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else the fixed in-checkout :data:`DEFAULT_DIR`."""
+    return os.environ.get(CACHE_ENV) or DEFAULT_DIR
 
 
 def persistent_dir() -> Optional[str]:
     return _persistent_dir
 
 
-def enable_persistent(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's compilation cache at ``path`` (default: ``cache_dir()``)
-    so XLA executables persist across processes.
+def enable_persistent() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return its directory.
 
-    Best-effort: returns the directory on success, None when this JAX
-    build lacks the config knobs (the process tier still works).  The
-    min-compile-time/entry-size floors are dropped to zero so the small
-    CPU-interpret serve functions are cached too — on a real TPU the
-    default floors would also admit them.
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and no
+    directory is set here; otherwise the cache goes to
+    :data:`DEFAULT_DIR`.  The min-compile-time/entry-size floors drop to
+    zero so every serve function is cached.  Any failure raises: a run
+    that asked for the cache never silently runs without it.
     """
     global _persistent_dir
-    path = path if path is not None else cache_dir()
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, val)
-            except (AttributeError, ValueError):
-                pass        # older JAX: floor stays at its default
-    except (AttributeError, ValueError, OSError):
-        _persistent_dir = None
-        return None
-    _persistent_dir = path
-    return path
+    if not os.environ.get(CACHE_ENV):
+        os.makedirs(DEFAULT_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _persistent_dir = cache_dir()
+    return _persistent_dir

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python); on a real TPU the same code lowers
-through Mosaic.  ``default_interpret()`` picks automatically.
+On a TPU the kernels lower through Mosaic; on the CPU backend they run
+with ``interpret=True`` (Pallas emulates the kernel body with XLA ops,
+which checks results but not what Mosaic accepts).
+``default_interpret()`` picks, and refuses any other backend.
 """
 
 from __future__ import annotations
@@ -19,7 +20,15 @@ from repro.kernels import xnor_matmul as _xm
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode exactly on the CPU backend, Mosaic on a TPU; any
+    other backend is an error, never a silent fallback."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU backend in "
+                       f"interpret mode), got backend {backend!r}")
 
 
 def pack(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:

@@ -24,7 +24,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.binarize import PACK_WIDTH, pack_bit_lanes
+from repro.core.binarize import PACK_WIDTH
+
+
+def pack_lanes(bits):
+    """(M, 32*Nw) int32 {0,1} -> (M, Nw) uint32, lane ``32j + i`` into bit
+    ``i`` of word ``j``.  Mosaic cannot split the lane axis, so the MXU
+    does the gather: two exact matmuls against one-hot power-of-two
+    matrices build each word's low and high 16 bits (sums < 2^16 are
+    exact in f32), and an int32 add (wrapping into bit 31) joins them."""
+    n = bits.shape[1]
+    shape = (n, n // PACK_WIDTH)
+    i = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    mine = (i >> 5) == jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    e = i & (PACK_WIDTH - 1)
+    x = bits.astype(jnp.bfloat16)
+
+    def half(lo: int):
+        pow2 = jnp.where(mine & (e >= lo) & (e < lo + 16),
+                         jnp.left_shift(1, e - lo), 0)
+        return jnp.dot(x, pow2.astype(jnp.float32).astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    words = half(0) + jnp.left_shift(half(16), 16)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _xnor_matmul_kernel(a_ref, w_ref, out_ref, *, k: int, nk: int):
@@ -72,8 +95,8 @@ def _xnor_matmul_pack_kernel(a_ref, w_ref, out_ref, acc_ref, *, k: int, nk: int)
     @pl.when(kb == nk - 1)
     def _finalize():
         s = jnp.int32(k) - 2 * acc_ref[...]               # (bm, bn) sums
-        bits = (s < 0).astype(jnp.uint32)                 # sign: bit=1 -> -1
-        out_ref[...] = pack_bit_lanes(bits)
+        bits = (s < 0).astype(jnp.int32)                  # sign: bit=1 -> -1
+        out_ref[...] = pack_lanes(bits)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "bn", "bk",
@@ -105,7 +128,9 @@ def xnor_matmul(a_words: jax.Array, w_words: jax.Array, *, k: int,
     bm = min(bm, m)
     bn = min(bn, n)
     if pack_out:
-        bn = -(-bn // PACK_WIDTH) * PACK_WIDTH    # whole words per tile
+        # one N tile: the packed output block's lane extent must be the
+        # whole N // 32 words (FC widths stay under 2048, so it is small)
+        bn = n
     bk = min(bk, kw)
     # pad to tile multiples (zero words == +1 signs on both sides: no-op)
     mp, np_, kp = (-m) % bm, (-n) % bn, (-kw) % bk

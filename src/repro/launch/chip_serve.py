@@ -8,9 +8,14 @@ programs — the chip's S-mode recombination across concurrent tasks), and
 the run closes with the host throughput plus the chip-model bill
 (µJ/frame, frames/s, average power analogue) from ``chip/energy.py``.
 
+It prints the device it runs on first (platform, kind, count) and keeps
+compiled programs in JAX's persistent cache (``kernels/cache.py``).
+
 Examples::
 
     PYTHONPATH=src python -m repro.launch.chip_serve --programs mnist5
+    PYTHONPATH=src python -m repro.launch.chip_serve --programs cifar9_s1 \
+        --requests 64 --batch 16 --megakernel
     PYTHONPATH=src python -m repro.launch.chip_serve \
         --programs mnist5,face_detector --requests 48 --batch 8 --shard
 
@@ -76,16 +81,20 @@ from repro.serving import CascadePipeline, ChipServer, make_trace, replay
 
 def build_artifact(program, seed: int, warm_bn: bool):
     """Packed deployment artifact for a program: init (+ optional one-batch
-    BN warm so thresholds are realistic), fold, bit-pack."""
-    key = jax.random.PRNGKey(seed)
-    params = interpreter.init_params(key, program)
-    if warm_bn:
-        io = program.instrs[0]
-        imgs = jax.random.randint(
-            jax.random.fold_in(key, 1),
-            (4, io.height, io.width, io.in_channels), 0, 2 ** io.bits)
-        _, params = interpreter.forward_train(params, program, imgs)
-    return interpreter.fold_params(params, program, packed=True)
+    BN warm so thresholds are realistic), fold, bit-pack — one jitted
+    program, so an accelerator compiles it once instead of op by op."""
+    @jax.jit
+    def build(key):
+        params = interpreter.init_params(key, program)
+        if warm_bn:
+            io = program.instrs[0]
+            imgs = jax.random.randint(
+                jax.random.fold_in(key, 1),
+                (4, io.height, io.width, io.in_channels), 0, 2 ** io.bits)
+            _, params = interpreter.forward_train(params, program, imgs)
+        return interpreter.fold_params(params, program, packed=True)
+
+    return build(jax.random.PRNGKey(seed))
 
 
 def frame_stream(program, n: int, seed: int):
@@ -216,6 +225,12 @@ def main(argv=None):
                          "the killed replica")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+
+    devs = jax.devices()
+    print(f"device: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}")
+    from repro.kernels import cache as warmcache
+    print(f"compile cache: {warmcache.enable_persistent()}")
 
     if args.cascade:
         return run_cascade(args)
@@ -424,13 +439,16 @@ def run_fleet(args, names, programs, artifacts, families):
         results = replay(fleet, trace, per)
     else:
         idx = {lane: 0 for lane in lanes}
+        results = []
         for submitted in range(args.requests):
             lane = lanes[submitted % len(lanes)]
             fleet.submit(lane, per[lane][idx[lane]])
             idx[lane] += 1
             if submitted % args.batch == args.batch - 1:
-                fleet.step()       # interleave serving so a --kill lands
-        results = fleet.drain()    # mid-stream, not after admission
+                # interleave serving so a --kill lands mid-stream, not
+                # after admission
+                results.extend(fleet.step())
+        results.extend(fleet.drain())
         results = sorted(results, key=lambda r: r.rid)
 
     st = fleet.stats()

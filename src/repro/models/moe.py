@@ -180,7 +180,7 @@ def apply_ep(params, cfg, x, mesh):
             aux = jax.lax.pmean(aux, ax)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = dctx.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, "model", None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
@@ -249,7 +249,7 @@ def apply_ep_decode(params, cfg, x, mesh):
             aux = jax.lax.pmean(aux, ax)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = dctx.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -20,6 +20,8 @@ Four property groups:
    bring-up is a cache hit.
 """
 
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -257,6 +259,46 @@ def test_serve_fn_key_schema(mnist_setup):
     assert k2 != k1
     k3 = warmcache.serve_fn_key((program,), interpret=True, kind="composite")
     assert k3 != k1
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_persistent_cache_placement(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and no directory is set in code;
+    without it the cache goes to the fixed in-checkout directory."""
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in knobs}
+    set_dirs = []
+    real_update = jax.config.update
+
+    def spy(name, val):
+        if name == "jax_compilation_cache_dir":
+            set_dirs.append(val)
+        real_update(name, val)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    monkeypatch.setattr(warmcache, "_persistent_dir", None)
+    if env_dir is None:
+        monkeypatch.delenv(warmcache.CACHE_ENV, raising=False)
+    else:
+        monkeypatch.setenv(warmcache.CACHE_ENV, str(tmp_path / env_dir))
+    try:
+        got = warmcache.enable_persistent()
+    finally:
+        for k, v in saved.items():
+            real_update(k, v)
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if env_dir is None:
+        assert got == warmcache.DEFAULT_DIR == os.path.join(
+            root, ".jax_compilation_cache")
+        assert set_dirs == [got]
+    else:
+        assert got == str(tmp_path / env_dir)
+        assert set_dirs == []
+    assert warmcache.stats()["persistent_dir"] == got
 
 
 def test_replacement_replica_warm_starts(mnist_setup):

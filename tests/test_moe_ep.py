@@ -32,7 +32,8 @@ _SCRIPT = textwrap.dedent("""
                       capacity_factor=8.0, impl="ep"),
         dtype="float32", param_dtype="float32")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh_for
+    mesh = make_mesh_for(8, model=4)
     key = jax.random.PRNGKey(0)
     params = moe.init(key, cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)
@@ -57,6 +58,7 @@ _SCRIPT = textwrap.dedent("""
 def test_ep_matches_dense_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"      # the child never reaches for a chip
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "MOE_EP_OK" in r.stdout, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"

@@ -58,6 +58,7 @@ _SCRIPT = textwrap.dedent("""
 def test_pipeline_matches_sequential_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"      # the child never reaches for a chip
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert "PIPELINE_OK" in r.stdout, \
