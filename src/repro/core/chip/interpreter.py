@@ -280,6 +280,10 @@ class InferencePlan:
         ``conv_tiles`` overrides the fused conv kernel's (bf, bb) tile
         sizes; default is the autotune cache's entry for this (program,
         backend, batch), falling back to the kernel defaults when cold.
+
+        Each stage runs in a ``jax.named_scope`` of its name (``encode``,
+        ``conv<i>``, ``fc<i>``), and each layer's kernel carries the same
+        name, so a profile lists device time per layer.
         """
         if conv_tiles is None:
             conv_tiles = autotune.conv_tiles(self.program, images.shape[0])
@@ -288,33 +292,45 @@ class InferencePlan:
         x = logits = None
         for st in self.stages:
             if isinstance(st, _IOStage):
-                x = na.thermometer_encode_packed(images, st.bits, st.channels)
+                with jax.named_scope("encode"):
+                    x = na.thermometer_encode_packed(images, st.bits,
+                                                     st.channels)
             elif isinstance(st, _ConvStage):
                 p = packed["conv"][ci]
-                x = kops.binary_conv2x2_block(
-                    x, p["w_words"], p["tau"], p["flip"], st.c,
-                    pool=st.pool, bf=bf, bb=bb, interpret=interpret)
+                name = f"conv{ci}"
+                with jax.named_scope(name):
+                    x = kops.binary_conv2x2_block(
+                        x, p["w_words"], p["tau"], p["flip"], st.c,
+                        pool=st.pool, bf=bf, bb=bb, interpret=interpret,
+                        name=name)
                 ci += 1
             else:
-                if x.ndim == 4:
-                    # packed (B, H, W, F//32) words flatten directly into
-                    # packed FC rows: F % 32 == 0 makes the word order the
-                    # row-major channel order.
-                    x = x.reshape(x.shape[0], -1)
-                p = packed["fc"][fi]
-                s = kops.xnor_matmul(x, p["w_words"], st.in_features,
-                                     pack_out=st.pack_out,
-                                     interpret=interpret)
-                if st.final:
-                    logits = s
-                elif st.pack_out:
-                    x = s
-                else:   # odd-width hidden FC: threshold at 0, repack
-                    x = binarize.pack_signs(
-                        binarize.hard_sign(s.astype(jnp.float32)), axis=-1)
+                name = f"fc{fi}"
+                with jax.named_scope(name):
+                    x, logits = self._fc(st, packed["fc"][fi], x, logits,
+                                         interpret, name)
                 fi += 1
         logits = logits.astype(jnp.float32)
         return logits, jnp.argmax(logits, axis=-1)
+
+    @staticmethod
+    def _fc(st, p, x, logits, interpret, name):
+        """One FC stage: (next packed input, logits once final)."""
+        if x.ndim == 4:
+            # packed (B, H, W, F//32) words flatten directly into packed
+            # FC rows: F % 32 == 0 makes the word order the row-major
+            # channel order.
+            x = x.reshape(x.shape[0], -1)
+        s = kops.xnor_matmul(x, p["w_words"], st.in_features,
+                             pack_out=st.pack_out, interpret=interpret,
+                             name=name)
+        if st.final:
+            return x, s
+        if st.pack_out:
+            return s, logits
+        # odd-width hidden FC: threshold at 0, repack
+        return binarize.pack_signs(
+            binarize.hard_sign(s.astype(jnp.float32)), axis=-1), logits
 
     def forward_mega(self, image, images: jax.Array,
                      interpret: bool | None = None,

@@ -46,5 +46,6 @@ def binarize_pack(x: jax.Array, *, bm: int = 256, interpret: bool = False) -> ja
         out_specs=pl.BlockSpec((bm, kw), lambda m_: (m_, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], kw), jnp.uint32),
         interpret=interpret,
+        name="binarize_pack",
     )(x)
     return out[:m]

@@ -93,6 +93,7 @@ def binary_conv2x2(a_words: jax.Array, w_words: jax.Array, *, c: int,
         out_shape=jax.ShapeDtypeStruct(
             (a_words.shape[0], h - 1, w - 1, w_words.shape[0]), jnp.int32),
         interpret=interpret,
+        name="binary_conv2x2",
     )(a_words, w_words)
     out = out[:b, :, :, :f]
     return out[0] if squeeze else out

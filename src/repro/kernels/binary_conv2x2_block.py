@@ -190,11 +190,13 @@ def unlane_map(x: jax.Array, ho: int, wo: int, row: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("c", "pool", "bf", "bb", "interpret"))
+                   static_argnames=("c", "pool", "bf", "bb", "interpret",
+                                    "name"))
 def binary_conv2x2_block(a_words: jax.Array, w_words: jax.Array,
                          tau: jax.Array, flip: jax.Array, *, c: int,
                          pool: bool = False, bf: int = 256, bb: int = 8,
-                         interpret: bool = False) -> jax.Array:
+                         interpret: bool = False,
+                         name: str = "binary_conv2x2_block") -> jax.Array:
     """Fused packed conv layer: packed words in, packed words out.
 
     a_words: (B, H, W, Cw) uint32 packed input feature maps (C channels).
@@ -207,6 +209,8 @@ def binary_conv2x2_block(a_words: jax.Array, w_words: jax.Array,
              frame is the int32 accumulator bf*P*4 B (1 MB at the worst
              chip shape: 32x32 map, bf=256); frames run one at a time
              through it, so ``bb`` only sets the DMA granule.
+    name:    the kernel's name in the compiled program and the profiler
+             (the staged plan passes ``conv<i>``, one per layer).
     Returns (B, Ho, Wo, F // 32) uint32 — Ho = (H-1)//2 if pool else H-1.
     """
     b, h, w, kw = a_words.shape
@@ -247,6 +251,7 @@ def binary_conv2x2_block(a_words: jax.Array, w_words: jax.Array,
         out_shape=jax.ShapeDtypeStruct((gf, a.shape[0], bfw, p), jnp.uint32),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name=name,
     )(a, nrows)
     out = out.transpose(1, 0, 2, 3).reshape(a.shape[0], gf * bfw, p)
     out = out[:b, :f // PACK_WIDTH]

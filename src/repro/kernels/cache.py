@@ -7,8 +7,8 @@ by something the chip never pays: tracing + XLA-compiling each resident
 program's serve function.  For a single server that cost amortizes; for
 a *fleet* it is the failover recovery path — a replacement replica's
 cold-start-to-first-served-frame is exactly one trace+compile of every
-resident program (tracked in the bench as
-``fleet_failover_recovery_ms`` / ``replica_warm_start_speedup``).
+resident program (the chip benchmark's ``fleet_recovery_ms``, with the
+build itself as ``replica_rebuild_ms``; ``BENCHMARK.json``).
 
 This module makes that start warm, following the autotuner's
 schema-versioned-key discipline (:mod:`repro.kernels.autotune`):

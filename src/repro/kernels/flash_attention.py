@@ -117,6 +117,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((g * bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
 
     out = out.reshape(b, kh, s, g, d).transpose(0, 2, 1, 3, 4)

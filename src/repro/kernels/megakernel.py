@@ -353,9 +353,10 @@ def _composite_kernel(*refs, spec, bb: int, n_tiles: int, ft: int,
                 out_copy(p, 1 - slot, i - 1).wait()
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "bb", "ft", "interpret"))
+@functools.partial(jax.jit, static_argnames=("spec", "bb", "ft", "interpret",
+                                             "name"))
 def composite_forward(image, frames, *, spec, bb: int = 8, ft=0,
-                      interpret: bool = False):
+                      interpret: bool = False, name: str = "composite"):
     """Multi-program packed inference in a single resident ``pallas_call``.
 
     image:  the composite weight image (``interpreter.pack_programs``) —
@@ -371,6 +372,8 @@ def composite_forward(image, frames, *, spec, bb: int = 8, ft=0,
     ft:     conv f-tile size; 0 = all F per chunk.  A tuple carries one
             f-tile per *member group* (``member_groups(spec)`` order) —
             groups with different sub-array widths tune separately.
+    name:   the kernel's name in the compiled program and the profiler
+            (``megakernel`` for the one-member case).
     Returns a tuple of (B_m, classes_m) int32 logits, one per member.
     """
     assert len(frames) == len(spec), (len(frames), len(spec))
@@ -411,6 +414,7 @@ def composite_forward(image, frames, *, spec, bb: int = 8, ft=0,
             + [pltpu.SemaphoreType.DMA((2,)) for _ in range(2 * nm)]),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name=name,
     )(*padded, *ops)
     outs = outs if isinstance(outs, (list, tuple)) else [outs]
     res = []
@@ -688,6 +692,7 @@ def cascade_forward(image, frames: jax.Array, ctrl, *, spec,
                        + _queue_scratch(bb),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="cascade",
     )(frames, ctrl, *ops)
     return (det[:b, d_off:d_off + ncd], rec[:b, r_off:r_off + ncr],
             qout[:b], cnt)
@@ -892,6 +897,7 @@ def delta_forward(image, frames: jax.Array, last, llog, ctrl, *, spec,
                        + _queue_scratch(bb),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="delta_gate",
     )(frames, ctrl, last, llog, *ops)
     new_last = unlane_map(new_last[:b], h, w, w, 1)
     return (logits[:b, n_off:n_off + ncls], new_last, qout[:b], cnt,
@@ -913,4 +919,5 @@ def megakernel_forward(image, frames: jax.Array, *, spec,
     Returns (B, classes) int32 logits.
     """
     return composite_forward(image, (frames,), spec=_solo_member_spec(spec),
-                             bb=bb, ft=ft, interpret=interpret)[0]
+                             bb=bb, ft=ft, interpret=interpret,
+                             name="megakernel")[0]

@@ -100,10 +100,11 @@ def _xnor_matmul_pack_kernel(a_ref, w_ref, out_ref, acc_ref, *, k: int, nk: int)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "bn", "bk",
-                                             "pack_out", "interpret"))
+                                             "pack_out", "interpret", "name"))
 def xnor_matmul(a_words: jax.Array, w_words: jax.Array, *, k: int,
                 bm: int = 128, bn: int = 128, bk: int = 64,
-                pack_out: bool = False, interpret: bool = False) -> jax.Array:
+                pack_out: bool = False, interpret: bool = False,
+                name: str = "xnor_matmul") -> jax.Array:
     """Packed binary matmul.
 
     a_words: (M, Kw) uint32 packed activations (+1 -> bit0, -1 -> bit1).
@@ -113,6 +114,8 @@ def xnor_matmul(a_words: jax.Array, w_words: jax.Array, *, k: int,
         kernel, returning (M, N // 32) uint32 instead of (M, N) int32 —
         the stay-binary path for hidden FC layers (requires N % 32 == 0).
     Returns (M, N) int32, or (M, N // 32) uint32 when ``pack_out``.
+    name:    the kernel's name in the compiled program and the profiler
+             (the staged plan passes ``fc<i>``, one per layer).
 
     The M axis doubles as the batch axis (callers flatten (B, K) frames
     into rows), and N is the outermost grid axis, so each weight tile is
@@ -156,6 +159,7 @@ def xnor_matmul(a_words: jax.Array, w_words: jax.Array, *, k: int,
                 jnp.uint32),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
             interpret=interpret,
+            name=name,
         )(a_words, w_words)
         return out[:m, :n // PACK_WIDTH]
 
@@ -166,5 +170,6 @@ def xnor_matmul(a_words: jax.Array, w_words: jax.Array, *, k: int,
         out_specs=pl.BlockSpec((bm, bn), lambda n_, m_, k_: (m_, n_)),
         out_shape=jax.ShapeDtypeStruct((a_words.shape[0], w_words.shape[0]), jnp.int32),
         interpret=interpret,
+        name=name,
     )(a_words, w_words)
     return out[:m, :n]

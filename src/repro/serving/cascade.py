@@ -273,12 +273,17 @@ class CascadePipeline:
     def _step_fused(self, reqs) -> List[CascadeResult]:
         """One fused dispatch: a detector batch through the in-kernel
         cascade; every frame in it finalizes immediately (escalated
-        frames carry the recognizer's answer from the same kernel)."""
+        frames carry the recognizer's answer from the same kernel).
+        The dispatch is the server's ``serve.step`` span."""
         srv = self.server
-        t0 = srv.clock()
+        with srv.probe.span("serve.step", dispatch=srv._dispatches):
+            return self._run_fused(reqs)
+
+    def _run_fused(self, reqs) -> List[CascadeResult]:
+        srv = self.server
         size = srv.batch
         frames = srv.executor.pad_frames(reqs, srv._geom[self.detector],
-                                         size)
+                                         size, srv._dispatches)
         ctrl = interpreter.CascadePlan.margin_ctrl(self.margin, len(reqs))
         dl, dlab, rl, rlab, queue, counts = self._fused["fn"](
             self._fused["image"], frames, ctrl)
@@ -323,7 +328,6 @@ class CascadePipeline:
                     rid=crid, label=int(rlab[k]), escalated=True,
                     detector_label=int(dlab[i]), detector_margin=m,
                     logits=rl[k]))
-        srv._host_wall_s += srv.clock() - t0
         return out
 
     def step(self) -> List[CascadeResult]:

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
@@ -43,6 +44,7 @@ import numpy as np
 from repro.core.chip import interpreter, isa
 from repro.distributed import sharding
 from repro.kernels import cache as warmcache
+from repro.serving import telemetry
 from repro.serving.policy import Dispatch
 from repro.serving.queue import FrameRequest, FrameResult
 
@@ -62,11 +64,15 @@ class Executor:
                  interpret: Optional[bool] = None,
                  megakernel: bool = False, prefetch: int = 0,
                  warm_start: bool = True,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 probe: Optional[telemetry.Probe] = None):
         self.batch = batch
         self.mesh = mesh
         self.prefetch = prefetch
         self.clock = clock
+        # the owning server's telemetry handle (serve.* stage spans)
+        self.probe = probe or telemetry.RECORDER.probe("executor", clock)
+        self._launched: set = set()      # (variants, size) run so far
         self._donate = donate_frames
         self._interpret = interpret
         self._megakernel = megakernel
@@ -220,69 +226,86 @@ class Executor:
 
     def pad_frames(self, reqs: List[FrameRequest],
                    geom: Tuple[int, int, int],
-                   size: Optional[int] = None):
+                   size: Optional[int] = None, dispatch: int = -1):
         """Stack a lane's pull into a batch of ``size`` (default: the
         static batch — the always-on pipeline doesn't idle: short lanes
         pad with the last real frame, empty lanes with zeros; the burned
-        slots are billed)."""
+        slots are billed).  ``dispatch`` is the span's id."""
         size = self.batch if size is None else size
-        if reqs:
-            frames = np.stack([r.frame for r in reqs])
-            if len(reqs) < size:
-                pad = np.broadcast_to(
-                    frames[-1], (size - len(reqs),) + frames.shape[1:])
-                frames = np.concatenate([frames, pad])
-        else:
-            frames = np.zeros((size,) + geom, dtype=np.int32)
+        with self.probe.span("serve.stack", dispatch=dispatch):
+            if reqs:
+                frames = np.stack([r.frame for r in reqs])
+                if len(reqs) < size:
+                    pad = np.broadcast_to(
+                        frames[-1], (size - len(reqs),) + frames.shape[1:])
+                    frames = np.concatenate([frames, pad])
+            else:
+                frames = np.zeros((size,) + geom, dtype=np.int32)
         return frames
 
     def launch(self, dispatch: Dispatch, index: int) -> Dict[str, Any]:
         """Run one policy decision on the device; returns the in-flight
         handle (device arrays, not yet synced)."""
         size = dispatch.batch if dispatch.batch is not None else self.batch
+        variants = tuple(ld.variant for ld in dispatch.lanes)
         if dispatch.composite:
-            variants = tuple(ld.variant for ld in dispatch.lanes)
             comp = self.composite_for(variants)
-            frames = []
-            for ld in dispatch.lanes:
-                f = jnp.asarray(self.pad_frames(list(ld.requests),
-                                                self._geom[ld.variant],
-                                                size))
-                if self.mesh is not None:
-                    f = sharding.scatter_frames(self.mesh, f)
-                frames.append(f)
-            logits, labels = comp["fn"](comp["image"], tuple(frames))
-            return dict(dispatch=dispatch, index=index, logits=logits,
-                        labels=labels)
-        ld, = dispatch.lanes
-        frames = jnp.asarray(self.pad_frames(list(ld.requests),
-                                             self._geom[ld.variant], size))
-        if self.mesh is not None:
-            frames = sharding.scatter_frames(self.mesh, frames)
-        logits, labels = self._fns[ld.variant](self.artifacts[ld.variant],
-                                               frames)
+            fn, art = comp["fn"], comp["image"]
+        else:
+            fn, art = self._fns[variants[0]], self.artifacts[variants[0]]
+        host = [self.pad_frames(list(ld.requests), self._geom[ld.variant],
+                                size, index) for ld in dispatch.lanes]
+        probe = self.probe
+        with probe.span("serve.put", dispatch=index):
+            frames = [jnp.asarray(f) for f in host]
+            if self.mesh is not None:
+                frames = [sharding.scatter_frames(self.mesh, f)
+                          for f in frames]
+        if (variants, size) not in self._launched:
+            self._launched.add((variants, size))
+            probe.count("serve.compile", dispatch=index,
+                        variants=",".join(variants), batch=size)
+        with probe.span("serve.launch", dispatch=index):
+            logits, labels = fn(art, tuple(frames) if dispatch.composite
+                                else frames[0])
+        # the launch stamp: how long the oldest frame of the batch queued
+        t_launch = probe.clock()
+        oldest = min((ld.requests[0].t_submit for ld in dispatch.lanes
+                      if ld.requests), default=0.0)
+        if oldest > 0.0:
+            probe.stamp("queue_wait", t_launch - oldest)
         return dict(dispatch=dispatch, index=index, logits=logits,
                     labels=labels)
 
     @staticmethod
-    def materialize(handle: Dict[str, Any]):
-        """Sync an in-flight dispatch's device arrays to host numpy (runs
-        on the fetch thread when prefetching)."""
-        if handle["dispatch"].composite:
-            labels = tuple(np.asarray(jax.block_until_ready(l))
-                           for l in handle["labels"])
-            logits = tuple(np.asarray(l) for l in handle["logits"])
-        else:
-            labels = np.asarray(jax.block_until_ready(handle["labels"]))
-            logits = np.asarray(handle["logits"])
+    def materialize(handle: Dict[str, Any], probe=None):
+        """Sync an in-flight dispatch's device arrays to host numpy: the
+        labels (``serve.wait``: the device's time and their transfer),
+        then the logits (``serve.fetch``).  The background fetch thread
+        calls it without a probe and records nothing."""
+        many = handle["dispatch"].composite
+        with (probe.span("serve.wait", dispatch=handle["index"])
+              if probe is not None else contextlib.nullcontext()):
+            labels = jax.block_until_ready(handle["labels"])
+            labels = (tuple(np.asarray(l) for l in labels) if many
+                      else np.asarray(labels))
+        with (probe.span("serve.fetch", dispatch=handle["index"])
+              if probe is not None else contextlib.nullcontext()):
+            logits = (tuple(np.asarray(l) for l in handle["logits"]) if many
+                      else np.asarray(handle["logits"]))
         return logits, labels
 
     def finish(self, handle: Dict[str, Any]) -> List[FrameResult]:
         """Block on an in-flight dispatch and materialize its results."""
         if "future" in handle:
-            logits, labels = handle["future"].result()
+            with self.probe.span("serve.wait", dispatch=handle["index"]):
+                logits, labels = handle["future"].result()
         else:
-            logits, labels = self.materialize(handle)
+            logits, labels = self.materialize(handle, self.probe)
+        with self.probe.span("serve.finish", dispatch=handle["index"]):
+            return self._results(handle, logits, labels)
+
+    def _results(self, handle, logits, labels) -> List[FrameResult]:
         dispatch: Dispatch = handle["dispatch"]
         t_done = self.clock()        # label available on the host, now
         if dispatch.composite:

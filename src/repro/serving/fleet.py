@@ -35,8 +35,9 @@ same ``submit/step/drain`` surface a single server exposes, so
   through the warm-start cache (:mod:`repro.kernels.cache`), a
   replacement on the same computation keys skips trace+compile — the
   kill-to-first-served-frame time is :attr:`ServeFleet.recovery_ms`,
-  tracked in the bench as ``fleet_failover_recovery_ms`` /
-  ``replica_warm_start_speedup``.
+  which the chip benchmark reports as ``fleet_recovery_ms``
+  (``BENCHMARK.json``); building the replacement is the ``fleet.replace``
+  span (``replica_rebuild_ms``).
 * **Stats** — :meth:`stats` merges per-replica books into
   :class:`FleetStats`: latency percentiles re-computed over the merged
   traces, served/padded/billed/energy summed (fleet-wide
@@ -57,6 +58,7 @@ from repro.checkpoint import ckpt
 from repro.core.chip import energy, isa
 from repro.distributed import fault, sharding
 from repro.kernels import cache as warmcache
+from repro.serving import telemetry as telemetry_mod
 from repro.serving.queue import FrameRequest, FrameResult
 from repro.serving.server import ChipServer, ServeStats
 
@@ -129,6 +131,10 @@ class ServeFleet:
     ``injector`` arms a :class:`FaultInjector`; ``replace=True`` rebuilds
     a killed host (``retries``/``backoff_s``/``sleep`` parameterize the
     :func:`~repro.distributed.fault.retry_step` bring-up loop).
+
+    Replicas record their ``serve.*`` spans under their names, and the
+    fleet its ``fleet.*`` spans, into ``telemetry`` (default: the
+    process-wide recorder, ``serving/telemetry.py``).
     """
 
     def __init__(self, programs: Mapping[str, isa.Program],
@@ -138,6 +144,7 @@ class ServeFleet:
                  replace: bool = False, retries: int = 2,
                  backoff_s: float = 0.0,
                  sleep=time.sleep, clock=time.perf_counter,
+                 telemetry: Optional[telemetry_mod.Recorder] = None,
                  **server_kw):
         if replicas < 1:
             raise ValueError(f"need >= 1 replica, got {replicas}")
@@ -149,7 +156,10 @@ class ServeFleet:
         self._sleep = sleep
         self._programs = dict(programs)
         self._artifacts = dict(artifacts)
-        self._server_kw = dict(server_kw, batch=batch, clock=clock)
+        telemetry = telemetry or telemetry_mod.RECORDER
+        self.probe = telemetry.probe("fleet", clock)
+        self._server_kw = dict(server_kw, batch=batch, clock=clock,
+                               telemetry=telemetry)
         self.batch = batch
         meshes = sharding.partition_serve_meshes(replicas, devices)
         self.replicas: Dict[str, ChipServer] = {}
@@ -158,7 +168,7 @@ class ServeFleet:
         for i, mesh in enumerate(meshes):
             name = f"host{i}"
             self.replicas[name] = ChipServer(
-                self._programs, self._artifacts, mesh=mesh,
+                self._programs, self._artifacts, mesh=mesh, replica=name,
                 **self._server_kw)
             self._devices[name] = list(mesh.devices.flatten())
             self._live.append(name)
@@ -171,6 +181,7 @@ class ServeFleet:
         self._refired = 0
         self.retry_stats: Dict[str, Any] = {}     # retry_step's out-dict
         self._recovery: Optional[Dict[str, Any]] = None
+        self._ticks = 0
 
     # -- surface (duck-types ChipServer for traffic.replay) -----------------
 
@@ -211,19 +222,22 @@ class ServeFleet:
     def step(self) -> List[FrameResult]:
         """One fleet tick: poll the fault injector, then one dispatch on
         every live replica.  Results are the concatenation, replica
-        order; [] once every replica is drained."""
-        if self.injector is not None:
-            victim = self.injector.poll(self)
-            if victim is not None:
-                self.fail(victim)
-        out: List[FrameResult] = []
-        for name in list(self._live):
-            got = self.replicas[name].step()
-            if got and self._recovery is not None and \
-                    self._recovery["t_first"] is None and \
-                    name == self._recovery["replica"]:
-                self._recovery["t_first"] = self.clock()
-            out.extend(got)
+        order; [] once every replica is drained.  The tick is the
+        ``fleet.step`` span; each replica's step is its ``serve.step``."""
+        self._ticks += 1
+        with self.probe.span("fleet.step", tick=self._ticks):
+            if self.injector is not None:
+                victim = self.injector.poll(self)
+                if victim is not None:
+                    self.fail(victim)
+            out: List[FrameResult] = []
+            for name in list(self._live):
+                got = self.replicas[name].step()
+                if got and self._recovery is not None and \
+                        self._recovery["t_first"] is None and \
+                        name == self._recovery["replica"]:
+                    self._recovery["t_first"] = self.clock()
+                out.extend(got)
         return out
 
     def drain(self) -> List[FrameResult]:
@@ -264,10 +278,16 @@ class ServeFleet:
         """Kill replica ``name``: harvest its unfinished frames, migrate
         them to the survivors' lane fronts, and (with ``replace=True``)
         bring up a replacement host on the victim's devices.  Returns
-        the migrated orphans by lane (order as re-enqueued)."""
+        the migrated orphans by lane (order as re-enqueued).  The kill
+        is the ``fleet.fail`` span; the replacement's build inside it is
+        ``fleet.replace``."""
         if name not in self.replicas or name in self._dead:
             raise KeyError(f"replica {name!r} not live "
                            f"(live: {self._live})")
+        with self.probe.span("fleet.fail", replica=name):
+            return self._fail(name)
+
+    def _fail(self, name: str) -> Dict[str, List[FrameRequest]]:
         t_kill = self.clock()
         victim = self.replicas.pop(name)
         self._live.remove(name)
@@ -304,12 +324,14 @@ class ServeFleet:
             mesh = ckpt.make_mesh((len(devs),), (sharding.SERVE_AXIS,),
                                   devices=devs)
             return ChipServer(self._programs, self._artifacts, mesh=mesh,
-                              **self._server_kw)
+                              replica=name, **self._server_kw)
 
         self.retry_stats = {}
-        replacement = fault.retry_step(
-            build, retries=self._retries, backoff_s=self._backoff_s,
-            sleep=self._sleep, stats=self.retry_stats)
+        with self.probe.span("fleet.replace", replica=name,
+                             victim=dead_name):
+            replacement = fault.retry_step(
+                build, retries=self._retries, backoff_s=self._backoff_s,
+                sleep=self._sleep, stats=self.retry_stats)
         self.replicas[name] = replacement
         self._devices[name] = devs
         self._live.append(name)
@@ -349,7 +371,7 @@ class ServeFleet:
         wall = 0.0
         billed = 0
         energy_uj = 0.0
-        lats: List[float] = []
+        lats: List[np.ndarray] = []
         for name, st in per.items():
             for lane in self.lanes:
                 served[lane] += st.served.get(lane, 0)
@@ -358,9 +380,10 @@ class ServeFleet:
             wall += st.host_wall_s
             billed += sum(st.served.values()) + sum(st.padded.values())
             energy_uj += st.energy_uj
-        for rec in self.latency_trace():
-            lats.append(rec["latency_ms"])
-        if lats:
+        for server in list(self.replicas.values()) + list(self._dead.values()):
+            lats.append(server._latency_s() * 1e3)
+        lats = np.concatenate(lats) if lats else np.zeros(0)
+        if len(lats):
             p50, p95, p99 = np.percentile(lats, [50, 95, 99])
         else:
             p50 = p95 = p99 = 0.0

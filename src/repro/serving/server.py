@@ -31,12 +31,14 @@ serves them through the operating-point controller (``policy=`` /
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.chip import energy, interpreter, isa
+from repro.serving import telemetry as telemetry_mod
 from repro.serving.executor import Executor
 from repro.serving.policy import (ContinuousPolicy, DispatchPolicy,
                                   OperatingPointPolicy, PolicyContext,
@@ -94,6 +96,10 @@ class ChipServer:
     (``budget_uj_s`` caps the chip-model average power in uJ/s);
     ``policy`` accepts a :class:`DispatchPolicy` instance or the strings
     ``"static"`` / ``"operating-point"``.
+
+    The server records its ``serve.*`` spans (``serving/telemetry.py``)
+    under ``replica`` into ``telemetry``, by default the process-wide
+    recorder, timed by ``clock``.
     """
 
     def __init__(self, programs: Mapping[str, isa.Program],
@@ -108,7 +114,9 @@ class ChipServer:
                  f_hz: float = energy.F_EMIN,
                  slo_ms: float = 50.0,
                  warm_start: bool = True,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter,
+                 replica: str = "server",
+                 telemetry: Optional[telemetry_mod.Recorder] = None):
         if set(programs) != set(artifacts):
             raise ValueError(
                 f"programs {sorted(programs)} != artifacts {sorted(artifacts)}")
@@ -128,6 +136,8 @@ class ChipServer:
         self.shared = shared
         self.slo_ms = slo_ms
         self.clock = clock                   # injectable for latency tests
+        self.probe = (telemetry or telemetry_mod.RECORDER).probe(replica,
+                                                                 clock)
         self.programs: Dict[str, isa.Program] = dict(programs)
 
         # -- lanes: families collapse their variants behind one lane -------
@@ -166,7 +176,8 @@ class ChipServer:
                                  mesh=mesh, donate_frames=donate_frames,
                                  interpret=interpret, megakernel=megakernel,
                                  prefetch=self.prefetch,
-                                 warm_start=warm_start, clock=clock)
+                                 warm_start=warm_start, clock=clock,
+                                 probe=self.probe)
         self.plans = self.executor.plans
         self.artifacts = self.executor.artifacts
         self.queue = FrameQueue(self._lanes)
@@ -205,11 +216,11 @@ class ChipServer:
         self._padded = {lane: 0 for lane in self._lanes}
         self._vserved = {name: 0 for name in self.programs}
         self._vpadded = {name: 0 for name in self.programs}
-        self._host_wall_s = 0.0
         self._billed = 0                     # frame slots launched (served
                                              # + padded, across all lanes)
-        self._latencies: List[float] = []    # stamped input-to-label, s
-        self._trace: List[Dict[str, Any]] = []   # per-frame latency trace
+        # per-frame latency books, one compact row per lane of a served
+        # dispatch: (dispatch, t_done, lane, variant, rids, t_submits)
+        self._done: List[tuple] = []
 
     def _make_policy(self, policy, budget_uj_s) -> DispatchPolicy:
         if isinstance(policy, DispatchPolicy):
@@ -255,6 +266,7 @@ class ChipServer:
         time); by default the server clock stamps *now*.  ``rid``
         overrides the locally-assigned id — a fleet hands out globally
         unique ids so results from different replicas never collide."""
+        t0 = self.clock()
         if program not in self._geom:
             raise KeyError(
                 f"program {program!r} not resident "
@@ -271,9 +283,10 @@ class ChipServer:
         else:
             self._next_rid = max(self._next_rid, rid + 1)
         if t_submit is None:
-            t_submit = self.clock()
+            t_submit = t0
         self.queue.submit(FrameRequest(rid=rid, program=program, frame=frame,
                                        t_submit=t_submit))
+        self.probe.add("serve.submit", self.clock() - t0)
         return rid
 
     def submit_many(self, program: str, frames) -> List[int]:
@@ -285,29 +298,32 @@ class ChipServer:
         """Consult the policy for the next dispatch, run it, and bill it.
         Serving counters are billed at launch — the energy is burned the
         moment the batch hits the array, synced or not."""
-        dispatch = self.policy.select(self.queue)
+        index = self._dispatches
+        with self.probe.span("serve.select", dispatch=index):
+            dispatch = self.policy.select(self.queue)
         if dispatch is None:
             return None
-        index = self._dispatches
         self._dispatches += 1
         handle = self.executor.launch(dispatch, index)
-        size = dispatch.batch if dispatch.batch is not None else self.batch
-        live = []
-        for ld in dispatch.lanes:
-            n = len(ld.requests)
-            self._served[ld.lane] += n
-            self._padded[ld.lane] += size - n
-            self._vserved[ld.variant] += n
-            self._vpadded[ld.variant] += size - n
-            self._billed += size
-            if n:
-                live.append(self.programs[ld.variant])
-        if dispatch.composite:
-            self._shared_dispatches += 1
-            self._util_sum += energy.array_occupancy(live)
-        else:
-            self._util_sum += 1.0 / self.programs[
-                dispatch.lanes[0].variant].s
+        with self.probe.span("serve.finish", dispatch=index):
+            size = (dispatch.batch if dispatch.batch is not None
+                    else self.batch)
+            live = []
+            for ld in dispatch.lanes:
+                n = len(ld.requests)
+                self._served[ld.lane] += n
+                self._padded[ld.lane] += size - n
+                self._vserved[ld.variant] += n
+                self._vpadded[ld.variant] += size - n
+                self._billed += size
+                if n:
+                    live.append(self.programs[ld.variant])
+            if dispatch.composite:
+                self._shared_dispatches += 1
+                self._util_sum += energy.array_occupancy(live)
+            else:
+                self._util_sum += 1.0 / self.programs[
+                    dispatch.lanes[0].variant].s
         return handle
 
     def step(self) -> List[FrameResult]:
@@ -321,25 +337,48 @@ class ChipServer:
         untouched.
 
         All timing goes through ``self.clock`` — the injected clock is
-        the server's single time domain (``_host_wall_s``, ``t_submit``,
+        the server's single time domain (``host_wall_s``, ``t_submit``,
         ``t_done`` and the latency trace all share it), so a
-        ``VirtualClock`` replay never silently mixes in wall time.
+        ``VirtualClock`` replay never silently mixes in wall time.  The
+        step is the ``serve.step`` span; ``host_wall_s`` is their sum.
         """
-        t0 = self.clock()
-        try:
+        with self.probe.span("serve.step", dispatch=self._dispatches):
             results = self.executor.step(self._launch)
-        finally:
-            self._host_wall_s += self.clock() - t0
-        for r in results:
-            if r.t_submit <= 0.0 or r.t_done <= 0.0:
-                continue                     # unstamped: no latency account
-            lat = r.t_done - r.t_submit
-            self._latencies.append(lat)
-            self._trace.append(dict(
-                rid=r.rid, lane=r.program, variant=r.variant,
-                dispatch=r.dispatch, t_submit=r.t_submit,
-                t_done=r.t_done, latency_ms=lat * 1e3))
+            if results:
+                with self.probe.span("serve.finish",
+                                     dispatch=results[0].dispatch):
+                    self._book(results)
         return results
+
+    def _book(self, results: List[FrameResult]) -> None:
+        """One latency row per lane of the dispatch these results came
+        from (a composite's lanes are contiguous in ``results``)."""
+        first, last = results[0], results[-1]
+        if (first.program, first.variant) == (last.program, last.variant):
+            groups = [results]
+        else:
+            groups = [list(g) for _k, g in itertools.groupby(
+                results, key=lambda r: (r.program, r.variant))]
+        for g in groups:
+            n = len(g)
+            self._done.append((
+                g[0].dispatch, g[0].t_done, g[0].program, g[0].variant,
+                np.fromiter((r.rid for r in g), np.int64, n),
+                np.fromiter((r.t_submit for r in g), np.float64, n)))
+
+    @property
+    def _host_wall_s(self) -> float:
+        """Seconds inside this server's steps since the last reset (the
+        sum of its ``serve.step`` spans)."""
+        return self.probe.seconds.get("serve.step", 0.0)
+
+    def _latency_s(self) -> np.ndarray:
+        """Input-to-label seconds of every stamped frame, completion
+        order."""
+        lats = [t_done - t_sub[t_sub > 0.0]
+                for _d, t_done, _l, _v, _r, t_sub in self._done
+                if t_done > 0.0]
+        return np.concatenate(lats) if lats else np.zeros(0)
 
     def drain(self) -> List[FrameResult]:
         """Serve until the queue is empty; results in dispatch order.
@@ -400,18 +439,29 @@ class ChipServer:
         self._padded = {lane: 0 for lane in self._lanes}
         self._vserved = {name: 0 for name in self.programs}
         self._vpadded = {name: 0 for name in self.programs}
-        self._host_wall_s = 0.0
+        self.probe.clear()
         self._billed = 0
-        self._latencies = []
-        self._trace = []
+        self._done = []
         for v in self.policy.variant_dispatches:
             self.policy.variant_dispatches[v] = 0
 
     def latency_trace(self) -> List[Dict[str, Any]]:
         """Per-frame admission-to-label records (stamped frames only), in
-        completion order — the artifact CI uploads next to the bench
-        JSON."""
-        return list(self._trace)
+        completion order, built from the per-dispatch rows when read.
+        Read by ``ServeFleet.latency_trace`` (merged over replicas), by
+        ``launch/chip_serve.py`` (the share within the SLO) and by
+        ``benchmarks/kernel_microbench.py`` (its latency trace file)."""
+        out: List[Dict[str, Any]] = []
+        for disp, t_done, lane, variant, rids, t_sub in self._done:
+            if t_done <= 0.0:
+                continue
+            for rid, ts in zip(rids.tolist(), t_sub.tolist()):
+                if ts > 0.0:
+                    out.append(dict(rid=rid, lane=lane, variant=variant,
+                                    dispatch=disp, t_submit=ts,
+                                    t_done=t_done,
+                                    latency_ms=(t_done - ts) * 1e3))
+        return out
 
     def stats(self) -> ServeStats:
         chip = energy.serve_report(self.programs, self._vserved,
@@ -427,8 +477,9 @@ class ChipServer:
             for v in self.programs)
         budget = getattr(self.policy, "budget_uj_s", None)
         vd = dict(self.policy.variant_dispatches)
-        if self._latencies:
-            p50, p95, p99 = np.percentile(self._latencies, [50, 95, 99])
+        lats = self._latency_s()
+        if len(lats):
+            p50, p95, p99 = np.percentile(lats, [50, 95, 99])
         else:
             p50 = p95 = p99 = 0.0
         padded = sum(self._padded.values())

@@ -320,15 +320,21 @@ class TemporalPipeline:
     def _step_gated(self, reqs) -> List[TemporalResult]:
         """One gated dispatch: a batch through the delta kernel; every
         frame in it finalizes immediately (skipped slots carry their
-        cached answer from the same kernel)."""
+        cached answer from the same kernel).  The dispatch is the
+        server's ``serve.step`` span."""
         srv = self.server
-        t0 = srv.clock()
+        with srv.probe.span("serve.step", dispatch=srv._dispatches):
+            return self._run_gated(reqs)
+
+    def _run_gated(self, reqs) -> List[TemporalResult]:
+        srv = self.server
         size = srv.batch
         n = len(reqs)
         variant = self._pick_variant(size)
         unit = srv.executor.delta_for(variant, rb=self.rb,
                                       check_every=self.check_every)
-        frames = srv.executor.pad_frames(reqs, srv._geom[self.lane], size)
+        frames = srv.executor.pad_frames(reqs, srv._geom[self.lane], size,
+                                         srv._dispatches)
         state = self._state.get(variant)
         if state is None:
             last, llog = unit["plan"].init_state(size)
@@ -381,7 +387,6 @@ class TemporalPipeline:
                 rid=self._rid.pop(r.rid), label=int(lb[i]),
                 computed=i in fresh, delta=int(deltas[i]),
                 variant=variant, logits=lg[i]))
-        srv._host_wall_s += srv.clock() - t0
         return out
 
     def step(self) -> List[TemporalResult]:

@@ -136,3 +136,22 @@ def test_delta_compiles(one_chip):
              _spec(one_chip, (BATCH, h, w, cw), jnp.uint32),
              _spec(one_chip, (BATCH, plan.classes), jnp.int32),
              _spec(one_chip, (1, 2), jnp.int32))
+
+
+@pytest.mark.parametrize("name", ["cifar9_s1", "cifar9_s4"])
+def test_staged_plan_names_each_layer_kernel(one_chip, name):
+    """The staged plan names each layer's Mosaic kernel after its stage
+    (``conv<i>``, ``fc<i>``), so a profile lists device time per layer."""
+    program = networks.REGISTRY[name]()
+    plan = interpreter.compile_plan(program)
+    packed = _packed(program)
+    text = jax.jit(lambda p, fr: plan.forward(p, fr, interpret=False)).lower(
+        _shapes(one_chip, packed),
+        _spec(one_chip, (BATCH, 32, 32, 3), jnp.int32)).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip().lstrip("%")
+               for ln in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    n_conv = len(packed["conv"])
+    names = {k.rsplit(".", 1)[0] for k in kernels}
+    assert names == {f"conv{i}" for i in range(n_conv)} | {"fc0"}, kernels
+    assert "%binary_conv2x2_block" not in text
