@@ -97,6 +97,14 @@ def build_artifact(program, seed: int, warm_bn: bool):
     return build(jax.random.PRNGKey(seed))
 
 
+def _prefetch_kw(args) -> dict:
+    """The pipeline depth the flags ask for, or nothing when neither is
+    given, so the server's default depth applies."""
+    if args.prefetch_depth is not None:
+        return {"prefetch": args.prefetch_depth}
+    return {"prefetch": 1} if args.prefetch else {}
+
+
 def frame_stream(program, n: int, seed: int):
     """Deterministic synthetic frames shaped for the program's IO layer."""
     io = program.instrs[0]
@@ -121,12 +129,13 @@ def main(argv=None):
                          "megakernel (weight image resident, zero HBM "
                          "traffic between layers)")
     ap.add_argument("--prefetch", action="store_true",
-                    help="double-buffer submission: stage batch N+1 while "
-                         "batch N runs, block only on fetch")
+                    help="double-buffer submission: launch batch N+1 "
+                         "before blocking on batch N (depth 1, the "
+                         "server's default)")
     ap.add_argument("--prefetch-depth", type=int, default=None,
-                    help="pipeline submission to depth k with async host "
-                         "result fetch (implies --prefetch; default 1 "
-                         "when --prefetch is set)")
+                    help="pipeline submission to depth k (0: synchronous; "
+                         "k >= 2 adds async host result fetch); without "
+                         "it or --prefetch the server's default applies")
     ap.add_argument("--shared", action="store_true",
                     help="shared-array dispatch: programs whose S-modes "
                          "tile the 256-channel array exactly run as ONE "
@@ -298,18 +307,16 @@ def main(argv=None):
 
     mesh = sharding.serve_mesh() if args.shard else None
     ndev = mesh.devices.size if mesh is not None else 1
-    prefetch = (args.prefetch_depth if args.prefetch_depth is not None
-                else int(args.prefetch))
     server = ChipServer(programs, artifacts, batch=args.batch, mesh=mesh,
                         donate_frames=args.donate,
-                        megakernel=args.megakernel, prefetch=prefetch,
+                        megakernel=args.megakernel, **_prefetch_kw(args),
                         shared=args.shared, policy=args.policy,
                         families=families or None,
                         budget_uj_s=args.budget_uj_s,
                         slo_ms=args.slo_ms)
     print(f"resident programs: {names}  (batch={args.batch}, "
           f"devices={ndev}, S-modes={[programs[n].s for n in names]}, "
-          f"megakernel={args.megakernel}, prefetch={prefetch}, "
+          f"megakernel={args.megakernel}, prefetch={server.prefetch}, "
           f"shared={args.shared}, policy={args.policy})")
     if families:
         for fam, members in families.items():
@@ -405,15 +412,13 @@ def run_fleet(args, names, programs, artifacts, families):
     replacement host."""
     from repro.serving import FaultInjector, ServeFleet
 
-    prefetch = (args.prefetch_depth if args.prefetch_depth is not None
-                else int(args.prefetch))
     injector = (FaultInjector(args.kill, after_served=args.kill_after)
                 if args.kill else None)
     fleet = ServeFleet(programs, artifacts, replicas=args.fleet,
                        batch=args.batch, injector=injector,
                        replace=not args.no_replace,
                        donate_frames=args.donate,
-                       megakernel=args.megakernel, prefetch=prefetch,
+                       megakernel=args.megakernel, **_prefetch_kw(args),
                        shared=args.shared, policy=args.policy,
                        families=families or None,
                        budget_uj_s=args.budget_uj_s, slo_ms=args.slo_ms)
@@ -496,10 +501,8 @@ def run_cascade(args):
           f"{det_name} -> {rec_name} ...")
     artifacts = {n: build_artifact(p, args.seed + i, not args.no_warm_bn)
                  for i, (n, p) in enumerate(programs.items())}
-    prefetch = (args.prefetch_depth if args.prefetch_depth is not None
-                else int(args.prefetch))
     server = ChipServer(programs, artifacts, batch=args.batch,
-                        megakernel=args.megakernel, prefetch=prefetch)
+                        megakernel=args.megakernel, **_prefetch_kw(args))
     casc = CascadePipeline(server, det_name, rec_name,
                            positive_class=1, margin=args.margin,
                            fused=args.fused)
@@ -563,10 +566,8 @@ def run_video(args):
     io = program.instrs[0]
     print(f"folding deployment artifact for {name} ...")
     artifact = build_artifact(program, args.seed, not args.no_warm_bn)
-    prefetch = (args.prefetch_depth if args.prefetch_depth is not None
-                else int(args.prefetch))
     server = ChipServer({name: program}, {name: artifact}, batch=args.batch,
-                        megakernel=args.megakernel, prefetch=prefetch)
+                        megakernel=args.megakernel, **_prefetch_kw(args))
     # fine-grained drain chunks: recompute work scales with the changed
     # count instead of rounding every dispatch up to a full batch
     pipe = temporal.TemporalPipeline(server, name,

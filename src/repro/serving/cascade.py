@@ -361,7 +361,7 @@ class CascadePipeline:
                 while True:
                     got = self.step()
                     out.extend(got)
-                    if not got and self.server.queue.pending() == 0:
+                    if not got and not self.server.owed():
                         return out
             finally:
                 self.server.policy.set_flush(False)
@@ -371,7 +371,7 @@ class CascadePipeline:
                 if self._deferred:
                     self._flush()          # trailing partial batch
                     continue
-                if self.server.queue.pending() == 0:
+                if not self.server.owed():
                     return out
                 continue
             out.extend(c for c in map(self._route, got) if c is not None)

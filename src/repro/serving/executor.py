@@ -19,10 +19,15 @@ here:
   dispatches in flight before blocking on the oldest one, with finished
   results fetched to host memory by a background thread; the policy is
   still consulted in exactly the synchronous order, so pipelining never
-  changes the schedule (property-tested).  At depth 1 the background
-  thread is skipped: with a single in-flight handle the consumer pops it
-  immediately, so a fetch thread adds handoff overhead without any
-  overlap to win (the BENCH prefetch-anomaly fix).
+  changes the schedule (property-tested).  Depth 1, the server's
+  default, launches the next ready dispatch before blocking on the
+  current one, so the host path of dispatch N+1 runs while the device
+  computes N; with nothing else ready the step is synchronous.  At
+  depth 1 the background thread is skipped: with a single in-flight
+  handle the consumer pops it immediately, so a fetch thread adds
+  handoff overhead without any overlap to win (the BENCH
+  prefetch-anomaly fix).  Each launch made while an earlier dispatch is
+  still in flight counts ``serve.ahead``.
 
 Dispatches carry their own pad target (``Dispatch.batch``): a continuous
 policy's early-and-small launches pad only to their bucket size, not the
@@ -62,7 +67,7 @@ class Executor:
                  artifacts: Mapping[str, Any], *, batch: int,
                  mesh=None, donate_frames: bool = False,
                  interpret: Optional[bool] = None,
-                 megakernel: bool = False, prefetch: int = 0,
+                 megakernel: bool = False, prefetch: int = 1,
                  warm_start: bool = True,
                  clock: Callable[[], float] = time.perf_counter,
                  probe: Optional[telemetry.Probe] = None):
@@ -329,13 +334,24 @@ class Executor:
 
     # -- the prefetch pipeline ----------------------------------------------
 
-    def _fill(self, launch_fn: Callable[[], Optional[Dict[str, Any]]]) -> None:
+    def inflight_frames(self) -> int:
+        """Real frames of the dispatches launched and not yet finished."""
+        return sum(len(ld.requests) for handle in self._inflight
+                   for ld in handle["dispatch"].lanes)
+
+    def _fill(self, launch_fn: Callable[[], Optional[Dict[str, Any]]],
+              busy: bool = False) -> None:
         """Launch dispatches until ``prefetch`` are in flight (or the
-        queue drains), handing each to the background fetch thread."""
+        policy has nothing ready), handing each to the background fetch
+        thread.  ``busy``: the caller holds a popped dispatch that is
+        still running, so every launch here runs ahead of it."""
         while len(self._inflight) < self.prefetch:
+            ahead = busy or bool(self._inflight)
             handle = launch_fn()
             if handle is None:
                 return
+            if ahead:
+                self.probe.count("serve.ahead")
             if self._fetch_pool is not None:
                 handle["future"] = self._fetch_pool.submit(
                     self.materialize, handle)
@@ -353,7 +369,7 @@ class Executor:
         if not self._inflight:
             return []
         cur = self._inflight.popleft()
-        self._fill(launch_fn)                  # stage N+1.. while N runs
+        self._fill(launch_fn, busy=True)       # launch N+1.. while N runs
         return self.finish(cur)
 
     def abort(self) -> List[FrameRequest]:

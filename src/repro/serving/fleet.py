@@ -241,7 +241,7 @@ class ServeFleet:
         return out
 
     def drain(self) -> List[FrameResult]:
-        """Serve until every live replica's queue is empty."""
+        """Serve until no live replica owes a frame."""
         out: List[FrameResult] = []
         flushed = set()
 
@@ -260,8 +260,7 @@ class ServeFleet:
                 out.extend(got)
                 if got:
                     continue
-                if not any(len(self.replicas[n].queue)
-                           for n in self._live):
+                if not any(self.replicas[n].owed() for n in self._live):
                     return out
         finally:
             for name in flushed:

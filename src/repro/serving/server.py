@@ -20,12 +20,12 @@ package is the TPU analogue of that controller, split mechanism/policy:
 
 All pre-split behaviour is preserved: ``megakernel=True`` runs dispatches
 through the whole-network resident kernel, ``prefetch=k`` pipelines
-submission to depth k, ``shared=True`` forms shared-array composite
-groups at admission, and a ``mesh`` replicates weights per device while
-frames scatter on the batch axis.  New: ``families=`` registers program
-families (variant sets of one task) behind a single queue lane and
-serves them through the operating-point controller (``policy=`` /
-``budget_uj_s=``).
+submission to depth k (default 1; 0 is synchronous), ``shared=True``
+forms shared-array composite groups at admission, and a ``mesh``
+replicates weights per device while frames scatter on the batch axis.
+New: ``families=`` registers program families (variant sets of one
+task) behind a single queue lane and serves them through the
+operating-point controller (``policy=`` / ``budget_uj_s=``).
 """
 
 from __future__ import annotations
@@ -84,7 +84,9 @@ class ChipServer:
     (``fold_params(..., packed=True)`` — float-folded artifacts are packed
     on admission).  ``batch`` is the static dispatch size; with a ``mesh``
     it must divide over the mesh's device count.  ``prefetch`` takes a
-    pipeline depth (``True`` = 1); ``shared=True`` forms shared-array
+    pipeline depth (``True`` = 1): by default 1, so each step launches
+    the next ready dispatch before blocking on the current one; 0 runs
+    every dispatch synchronously.  ``shared=True`` forms shared-array
     composite groups at admission.
 
     ``families`` maps a family (task) name to a sequence of resident
@@ -106,7 +108,7 @@ class ChipServer:
                  artifacts: Mapping[str, Any], *, batch: int = 8,
                  mesh=None, donate_frames: bool = False,
                  interpret: Optional[bool] = None,
-                 megakernel: bool = False, prefetch: bool | int = False,
+                 megakernel: bool = False, prefetch: bool | int = 1,
                  shared: bool = False,
                  policy: Optional[DispatchPolicy | str] = None,
                  families: Optional[Mapping[str, Sequence[str]]] = None,
@@ -330,17 +332,21 @@ class ChipServer:
         """One dispatch: pull a static batch, run its program(s), return
         results for the real (non-padding) frames.  [] once drained.
 
-        With ``prefetch=k`` up to k batches are staged and dispatched
-        *before* blocking on the oldest one, and finished results are
-        pulled to the host by a background thread; batches still leave
-        the queue in exactly the synchronous order, so fairness is
-        untouched.
+        With ``prefetch=k`` (k >= 1; the default is 1) up to k batches
+        are staged and dispatched *before* blocking on the oldest one,
+        and at k >= 2 finished results are pulled to the host by a
+        background thread; batches still leave the queue in exactly the
+        synchronous order, so fairness is untouched.  A step's results
+        are those of the dispatch it blocked on; the next one may
+        already be in flight (:meth:`owed` counts it).
 
         All timing goes through ``self.clock`` — the injected clock is
         the server's single time domain (``host_wall_s``, ``t_submit``,
         ``t_done`` and the latency trace all share it), so a
         ``VirtualClock`` replay never silently mixes in wall time.  The
-        step is the ``serve.step`` span; ``host_wall_s`` is their sum.
+        step is the ``serve.step`` span, carrying the index of the next
+        dispatch to launch as the step began; ``host_wall_s`` is their
+        sum.
         """
         with self.probe.span("serve.step", dispatch=self._dispatches):
             results = self.executor.step(self._launch)
@@ -380,8 +386,15 @@ class ChipServer:
                 if t_done > 0.0]
         return np.concatenate(lats) if lats else np.zeros(0)
 
+    def owed(self) -> int:
+        """Frames submitted and not yet answered: queued, or in a
+        dispatch still in flight.  With a pipeline the queue can be
+        empty while a dispatch is owed, so callers that serve until
+        nothing is owed test this, not the queue."""
+        return len(self.queue) + self.executor.inflight_frames()
+
     def drain(self) -> List[FrameResult]:
-        """Serve until the queue is empty; results in dispatch order.
+        """Serve until nothing is owed; results in dispatch order.
         The policy is flushed for the duration: a continuous policy's
         admission window never holds the final ragged batches back."""
         out: List[FrameResult] = []
@@ -389,7 +402,7 @@ class ChipServer:
         try:
             while True:
                 got = self.step()
-                if not got and not len(self.queue):
+                if not got and not self.owed():
                     return out
                 out.extend(got)
         finally:

@@ -36,6 +36,9 @@ Names (``docs/serving.md``, "Telemetry"):
   (logits to host) and ``serve.finish`` (results and the server's books);
 * ``serve.compile`` (counter, with the dispatch index) — launches of a
   (variant, batch size) the executor has not run before;
+* ``serve.ahead`` (counter, no events) — dispatches launched while an
+  earlier dispatch of the same server was still in flight: over the
+  ``serve.launch`` count, the share of dispatches the pipeline overlapped;
 * ``fleet.step``, ``fleet.fail``, ``fleet.replace`` — one fleet tick; a
   kill's harvest and requeue; building the replacement replica.
 """
@@ -191,7 +194,10 @@ class Probe:
         tot[2] += seconds
 
     def count(self, name: str, n: int = 1, **ids) -> None:
-        ids.setdefault("replica", self.replica)
+        """Add ``n`` to a counter; with ids, also keep the event, under
+        this probe's replica (a per-dispatch counter passes none)."""
+        if ids:
+            ids.setdefault("replica", self.replica)
         self.recorder.count(name, n, **ids)
 
     def stamp(self, column: str, value: float) -> None:

@@ -409,7 +409,7 @@ class TemporalPipeline:
             while True:
                 got = self.step()
                 out.extend(got)
-                if not got and self.server.queue.pending() == 0:
+                if not got and not self.server.owed():
                     return out
         finally:
             self.server.policy.set_flush(False)

@@ -170,17 +170,20 @@ def test_cascade_billing_ragged_drain(cascade_setup):
         (8 * det_uj + 4 * rec_uj) / 7)
 
 
-def test_cascade_report_midstream_never_bills_queued(cascade_setup):
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_cascade_report_midstream_never_bills_queued(cascade_setup,
+                                                     prefetch):
     """A mid-stream report bills only what hit the array: frames still
     queued on the detector — or escalations deferred awaiting a full
-    recognizer batch — are absent from the bill until dispatched."""
+    recognizer batch — are absent from the bill until dispatched.  At
+    depth 1 the step has launched the next detector batch too."""
     det, rec, arts, frames, _, _ = cascade_setup
-    server = _server(det, rec, arts)
+    server = _server(det, rec, arts, prefetch=prefetch)
     casc = CascadePipeline(server, "det", "rec", margin=float("-inf"))
     casc.submit_many(frames[:5])
     casc.step()                               # one det dispatch of 2
-    rep = casc.report()
-    assert rep.frames == 2                    # 3 still queued
+    rep = casc.report()                       # (and one in flight at
+    assert rep.frames == 2 * (1 + prefetch)   # depth 1); the rest queued
     # both frames escalated but the recognizer batch is still deferred
     assert casc.escalated == 2 and rep.escalated == 0
     casc.drain()

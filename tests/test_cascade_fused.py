@@ -203,6 +203,30 @@ def test_fused_billing_invariant_and_kernel_slots(fused_setup):
     server.close()
 
 
+@pytest.mark.parametrize("prefetch", [0, 1, 2])
+def test_fused_drain_answers_a_second_lane(fused_setup, prefetch):
+    """The fused drain serves the server's other lanes until nothing is
+    owed: with a pipeline the step that answers one of their dispatches
+    may launch the last one and empty the queue, and that dispatch is
+    still answered."""
+    det, rec, progs, arts, frames, *_ = fused_setup
+    other = networks.mnist5(classes=7)
+    oart = _artifact(other, seed=9)
+    server = ChipServer({**progs, "other": other}, {**arts, "other": oart},
+                        batch=2, interpret=True, prefetch=prefetch)
+    other_frames = _frames(other, 4, seed=8)       # two whole batches
+    oracle = _offline(other, oart, other_frames)[1]
+    casc = CascadePipeline(server, "det", "rec", fused=True)
+    casc.submit_many(frames)
+    other_rids = server.submit_many("other", other_frames)
+    assert len(casc.drain()) == len(frames)
+    got = {r.rid: r.label for r in casc.other_results}
+    assert sorted(got) == other_rids
+    np.testing.assert_array_equal([got[r] for r in other_rids], oracle)
+    assert server.owed() == 0
+    server.close()
+
+
 def test_fused_warm_cache_and_positive_class_key(fused_setup):
     """The fused dispatch routes through the warm-start cache: a second
     pipeline over the same pair warm-starts (cache hit), while a

@@ -236,6 +236,18 @@ def test_prefetch_depth_k_interleaved_with_submission(mnist_setup):
                                   labels_ref)
 
 
+def test_prefetch_defaults_to_depth_one():
+    """A server pipelines one dispatch ahead unless told otherwise;
+    ``prefetch=0`` keeps every dispatch synchronous."""
+    program = networks.mnist5()
+    packed = _artifact(program)
+    assert ChipServer({"m": program}, {"m": packed},
+                      interpret=True).prefetch == 1
+    sync = ChipServer({"m": program}, {"m": packed}, interpret=True,
+                      prefetch=0)
+    assert sync.prefetch == 0 and sync.executor.prefetch == 0
+
+
 def test_prefetch_bool_is_depth_one():
     """Back-compat: prefetch=True means a depth-1 pipeline."""
     program = networks.mnist5()

@@ -319,6 +319,37 @@ def test_pipeline_gate_off_matches_ungated(pipe_setup):
     assert pipe.report().skip_ratio == 0.0
 
 
+@pytest.mark.parametrize("prefetch", [0, 1, 2])
+def test_pipeline_drain_answers_a_second_lane(pipe_setup, prefetch):
+    """The temporal drain serves the server's other lanes until nothing
+    is owed: with a pipeline the step that answers one of their
+    dispatches may launch the last one and empty the queue, and that
+    dispatch is still answered."""
+    prog, art, trace, oracle = pipe_setup
+    other = networks.mnist5(classes=7)
+    oart = _artifact(other, seed=9)
+    batch = trace.streams
+    srv = ChipServer({"m": prog, "other": other}, {"m": art, "other": oart},
+                     batch=batch, interpret=True, prefetch=prefetch)
+    pipe = tmp.TemporalPipeline(srv, "m", threshold=1.0, rb=1)
+    for t in range(2):
+        for s in range(trace.streams):
+            pipe.submit(trace.frames[t, s])
+    other_frames = _frames(other, 2 * batch, seed=8)  # two whole batches
+    other_rids = srv.submit_many("other", other_frames)
+    res = pipe.drain()
+    got = np.array([r.label for r in sorted(res, key=lambda r: r.rid)])
+    assert np.array_equal(got, oracle[:2 * trace.streams])
+    plan = interpreter.compile_plan(other)
+    _, want = plan.forward(interpreter.ensure_packed(oart), other_frames,
+                           interpret=True)
+    by_rid = {r.rid: r.label for r in pipe.other_results}
+    assert sorted(by_rid) == other_rids
+    np.testing.assert_array_equal([by_rid[r] for r in other_rids],
+                                  np.asarray(want))
+    assert srv.owed() == 0
+
+
 def test_pipeline_reset_recomputes(pipe_setup):
     """reset() drops the resident state: the next dispatch recomputes
     every stream even when frames did not change."""

@@ -152,13 +152,17 @@ def test_failover_zero_loss_bit_exact_mid_replay(mnist_setup):
     assert st.total_served == n + st.refired_frames
 
 
-def test_migration_preserves_per_lane_order(mnist_setup):
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_migration_preserves_per_lane_order(mnist_setup, prefetch):
     """Migrated frames enter the survivor's lane front: they keep their
     own relative order and serve before anything routed to the survivor
-    after the failure; the survivor's own frames also stay in order."""
+    after the failure; the survivor's own frames also stay in order.
+    At depth 1 each replica already has its next dispatch in flight:
+    the victim's is migrated, the survivor's is answered first."""
     program, packed, frames, _ = mnist_setup
     vc = VirtualClock()
-    fleet = _fleet(program, packed, vc, batch=2, replace=False)
+    fleet = _fleet(program, packed, vc, batch=2, replace=False,
+                   prefetch=prefetch)
     # blocks of 2: rids 0,1 -> host0; 2,3 -> host1; 4,5 -> host0; 6,7 -> host1
     for f in frames[:8]:
         fleet.submit("mnist5", f)
@@ -166,15 +170,18 @@ def test_migration_preserves_per_lane_order(mnist_setup):
     assert len(first) == 4
     orphans = fleet.fail("host0")
     migrated = [r.rid for r in orphans["mnist5"]]
-    assert migrated == [4, 5]        # host0's queued backlog, in order
+    assert migrated == [4, 5]        # host0's unserved frames, in order
+    ahead = fleet.replicas["host1"].executor.inflight_frames()
+    assert ahead == 2 * prefetch     # the survivor's launched dispatch
     post = [fleet.submit("mnist5", f) for f in frames[8:12]]
     results = fleet.drain()
     served_after = [r.rid for r in results]
     # zero loss: everything not already served comes out of the drain
     assert sorted(served_after) == [4, 5, 6, 7] + post
-    # migrated frames first (in order), then the survivor's own queue,
-    # then the post-failure admissions
-    assert served_after[:2] == [4, 5]
+    # migrated frames first (in order) after what the survivor had in
+    # flight, then the survivor's own queue, then the post-failure
+    # admissions
+    assert served_after[ahead:ahead + 2] == [4, 5]
     assert served_after.index(6) < served_after.index(7)
     assert max(served_after.index(r) for r in [4, 5, 6, 7]) < \
         min(served_after.index(r) for r in post)
@@ -225,6 +232,32 @@ def test_fleet_billing_with_padding_and_failure(mnist_setup):
     assert "host0" in st.replicas
     dead = st.replicas["host0"]
     assert sum(dead.served.values()) + sum(dead.padded.values()) > 0
+
+
+def test_default_depth_kill_loses_and_duplicates_nothing(mnist_setup):
+    """At the default depth (1) every replica keeps its next dispatch in
+    flight, so a kill aborts real in-flight work: every frame is still
+    answered exactly once, bit-exact, and the aborted frames are billed
+    again by whoever serves them."""
+    program, packed, frames, labels = mnist_setup
+    vc = VirtualClock()
+    fleet = _fleet(program, packed, vc, batch=2,
+                   injector=FaultInjector("host0", after_served=4),
+                   replace=True)
+    assert all(s.prefetch == 1 for s in fleet.replicas.values())
+    n = len(frames)
+    for f in frames:
+        fleet.submit("mnist5", f)
+    results = fleet.drain()
+    rids = [r.rid for r in results]
+    assert sorted(rids) == list(range(n))          # none lost, none twice
+    got = {r.rid: r.label for r in results}
+    np.testing.assert_array_equal([got[i] for i in range(n)], labels)
+    st = fleet.stats()
+    assert st.failed_replicas == ("host0",)
+    assert st.refired_frames > 0
+    assert st.total_served == n + st.refired_frames
+    assert st.billed == st.total_served + sum(st.padded.values())
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,8 @@ counters, and what the servers record into them.
 * the per-step ring: one row per ``serve.step`` with each stage's self
   seconds, percentiles as numpy computes them, wrap-around and reset;
 * the servers: a frozen ``VirtualClock`` records zeros, ``serve.compile``
-  counts each new (variant, batch size) once, a fleet's kill records
+  counts each new (variant, batch size) once, ``serve.ahead`` each
+  launch made with a dispatch in flight, a fleet's kill records
   ``fleet.fail``/``fleet.replace`` under replica names, and a profiler
   trace holds the spans with their dispatch ids.
 """
@@ -180,13 +181,18 @@ def test_frozen_virtual_clock_records_zero_durations(mnist):
     assert server.stats().host_wall_s == 0.0
 
 
-def test_server_books_come_from_its_spans(mnist):
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_server_books_come_from_its_spans(mnist, prefetch):
     """``host_wall_s`` is the sum of the server's ``serve.step`` spans
-    since its last reset; the recorder keeps its totals across it."""
+    since its last reset; the recorder keeps its totals across it.  A
+    row's ``dispatch`` is the step's entry index: synchronous steps
+    launch 0 and 1; at depth 1 the first step launches both and the
+    second, entered at index 2, only waits on 1."""
     program, packed, frames = mnist
     rec = telemetry.Recorder()
     server = ChipServer({"m": program}, {"m": packed}, batch=4,
-                        interpret=True, telemetry=rec, replica="cam7")
+                        interpret=True, telemetry=rec, replica="cam7",
+                        prefetch=prefetch)
     for f in frames[:8]:
         server.submit("m", f)
     server.drain()
@@ -198,8 +204,12 @@ def test_server_books_come_from_its_spans(mnist):
     assert rec.snapshot()["spans"]["serve.step"]["count"] == tot["count"]
     rows = rec.rows()
     assert set(rows["replica"]) == {"cam7"}
-    assert rows["dispatch"].tolist() == [0, 1]
-    assert (rows["wait"] > 0).all() and (rows["queue_wait"] > 0).all()
+    assert rows["dispatch"].tolist() == ([0, 1] if prefetch == 0
+                                         else [0, 2])
+    assert (rows["wait"] > 0).all()
+    launched = rows["launch"] > 0            # depth 1: the second step
+    assert launched.tolist() == [True, prefetch == 0]   # launches none
+    assert (rows["queue_wait"][launched] > 0).all()
     assert (rows["step"] >= rows["wait"] + rows["launch"]).all()
 
 
@@ -221,6 +231,26 @@ def test_compile_counts_once_per_new_shape(mnist):
     assert sorted(e["variants"] for e in snap["events"]) == ["a", "b"]
     assert sorted(e["dispatch"] for e in snap["events"]) == [0, 1]
     assert all(e["batch"] == 4 for e in snap["events"])
+
+
+@pytest.mark.parametrize("prefetch", [0, 1, 2])
+def test_ahead_counts_overlapped_launches(mnist, prefetch):
+    """``serve.ahead`` counts launches made while an earlier dispatch was
+    in flight: on a full backlog every dispatch but the first at any
+    depth >= 1, none when synchronous.  It keeps no events."""
+    program, packed, frames = mnist
+    rec = telemetry.Recorder()
+    server = ChipServer({"m": program}, {"m": packed}, batch=4,
+                        interpret=True, telemetry=rec, prefetch=prefetch)
+    for f in frames:
+        server.submit("m", f)
+    assert len(server.drain()) == len(frames)
+    snap = rec.snapshot()
+    dispatches = snap["spans"]["serve.launch"]["count"]
+    assert dispatches == server.stats().dispatches == 4
+    assert snap["counters"].get("serve.ahead", 0) == (
+        dispatches - 1 if prefetch else 0)
+    assert all(e["name"] != "serve.ahead" for e in snap["events"])
 
 
 def test_latency_trace_expands_compact_rows(mnist):
@@ -250,11 +280,18 @@ def test_latency_trace_expands_compact_rows(mnist):
                                       * 1e3)
 
 
-def test_profiler_trace_holds_serve_spans_with_dispatch_ids(mnist, tmp_path):
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_profiler_trace_holds_serve_spans_with_dispatch_ids(mnist, tmp_path,
+                                                            prefetch):
+    """Each dispatch's spans carry its index.  At depth 1 a step holds
+    the launch of N+1 and the wait of N, so step ids follow the entry
+    index while each dispatch still has one launch and one wait."""
     from jax.profiler import ProfileData
     program, packed, frames = mnist
+    rec = telemetry.Recorder()
     server = ChipServer({"m": program}, {"m": packed}, batch=4,
-                        interpret=True, replica="traced0")
+                        interpret=True, replica="traced0", telemetry=rec,
+                        prefetch=prefetch)
     for f in frames[:4]:
         server.submit("m", f)
     server.drain()                       # compile outside the trace
@@ -279,11 +316,16 @@ def test_profiler_trace_holds_serve_spans_with_dispatch_ids(mnist, tmp_path):
                 if ev.name.startswith("serve."):
                     st = {k: v for k, v in ev.stats}
                     if st.get("replica") == "traced0":
-                        seen.setdefault(ev.name, set()).add(
+                        seen.setdefault(ev.name, []).append(
                             int(st["dispatch"]))
-    assert seen["serve.step"] >= {1, 2, 3}
-    assert seen["serve.wait"] == {1, 2, 3}
-    assert seen["serve.launch"] == {1, 2, 3}
+    assert sorted(seen["serve.wait"]) == [1, 2, 3]   # one per dispatch
+    assert sorted(seen["serve.launch"]) == [1, 2, 3]
+    if prefetch == 0:
+        assert set(seen["serve.step"]) >= {1, 2, 3}
+    else:                 # launch 1 and 2, wait 1; launch 3, wait 2; ...
+        assert set(seen["serve.step"]) >= {1, 3, 4}
+    rows = rec.rows()
+    assert (rows["step"] >= rows["wait"]).all()
 
 
 _FLEET = textwrap.dedent("""
