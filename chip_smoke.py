@@ -216,7 +216,7 @@ def phase_d() -> dict:
                         batch=batch)
     casc = CascadePipeline(server, det, rec, margin=0.0, fused=True)
     frames = frames_for(progs[det], 2 * batch, SEED + 21)
-    unit = casc._fused
+    unit = server.executor.cascade_for(det, rec)    # the served unit
     ctrl = interpreter.CascadePlan.margin_ctrl(0.0, batch)
     compile_s = mosaic_compile(unit["fn"], unit["image"],
                                jax.numpy.asarray(frames[:batch]), ctrl)

@@ -43,9 +43,13 @@ host cascade for every margin (the kernel compares the integer logit
 margin against ``ceil(margin)`` — equivalent for integer logits — see
 ``CascadePlan.margin_ctrl``); the energy bill is identical in shape
 (detector on every slot, recognizer on the escalated count the kernel
-reports back, plus its drain-chunk padding).  Fused dispatches are
-compiled lazily through :meth:`Executor.cascade_for` and the warm-start
-cache, like any composite.
+reports back, plus its drain-chunk padding).  The pipeline binds the
+detector lane as a cascade on the server (:meth:`ChipServer.
+bind_cascade`), so a fused dispatch is an ordinary ``ChipServer.step``:
+the policy selects the detector batch, the executor launches it through
+the cached cascade unit (:meth:`Executor.cascade_for`, warm-start
+cache), pipelines it like any dispatch, and bills it when it finishes;
+this module only maps the server's results to :class:`CascadeResult`.
 
 **Margin calibration** (:func:`calibrate_margin`): instead of picking
 the escalation margin by eyeball, run the detector offline on a
@@ -64,17 +68,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.core.chip import energy, interpreter
-from repro.serving.queue import FrameResult
+from repro.serving.queue import FrameResult, margins_of
 from repro.serving.server import ChipServer
-
-
-def margins_of(logits, positive_class: int = 1) -> np.ndarray:
-    """Vectorized escalation margins: positive-class logit minus the best
-    competing logit, float64, one per row of ``logits``."""
-    lg = np.asarray(logits, dtype=np.float64)
-    pos = lg[:, positive_class]
-    rest = np.delete(lg, positive_class, axis=1).max(axis=1)
-    return pos - rest
 
 
 def margin_for_recall(margins, labels, target_recall: float) -> float:
@@ -148,14 +143,14 @@ class CascadePipeline:
     escalate every positive-labelled frame).
 
     ``fused=True`` serves the hierarchy as ONE kernel dispatch per
-    detector batch: frames still enqueue on the detector lane, but each
-    step pulls a batch and runs it through the fused cascade kernel
-    (``Executor.cascade_for``) — detector, in-kernel escalation mask,
+    detector batch: frames enqueue on the detector lane, which the
+    server dispatches through the fused cascade kernel
+    (``ChipServer.bind_cascade``) — detector, in-kernel escalation mask,
     and recognizer-over-escalated-lanes in a single ``pallas_call``.
-    Labels are bit-exact vs the host path for every margin; results
-    finalize in the same step (no deferred recognizer batches).  Lanes
-    outside the cascade still serve through the ordinary server path in
-    either mode.
+    Labels are bit-exact vs the host path for every margin; every frame
+    of a dispatch finalizes when it finishes (no deferred recognizer
+    batches).  Lanes outside the cascade serve through the same server
+    steps in either mode.
     """
 
     def __init__(self, server: ChipServer, detector: str, recognizer: str,
@@ -182,16 +177,10 @@ class CascadePipeline:
         self.detector = detector
         self.recognizer = recognizer
         self.positive_class = positive_class
-        self.margin = margin
         self.fused = fused
+        self.margin = margin
         self._det_variant = server._lane_variants[detector][0]
         self._rec_variant = server._lane_variants[recognizer][0]
-        # the fused dispatch unit compiles eagerly (like warm_composites:
-        # resident programs load their weights before serving) and routes
-        # through the executor's warm-start cache
-        self._fused = (server.executor.cascade_for(
-            self._det_variant, self._rec_variant,
-            positive_class=positive_class) if fused else None)
         self.fused_dispatches = 0
         self._next_rid = 0
         self._frames: Dict[int, np.ndarray] = {}   # srid -> frame (det stage)
@@ -204,6 +193,20 @@ class CascadePipeline:
                                                     # outside the cascade
         self._submitted = 0
         self._escalated = 0
+
+    @property
+    def margin(self) -> float:
+        return self._margin_thr
+
+    @margin.setter
+    def margin(self, margin: float) -> None:
+        """The escalation threshold; in fused mode the server's cascade
+        route carries it to every later dispatch."""
+        self._margin_thr = margin
+        if self.fused:
+            self.server.bind_cascade(self.detector, self.recognizer,
+                                     positive_class=self.positive_class,
+                                     margin=margin)
 
     # -- request side -------------------------------------------------------
 
@@ -239,6 +242,12 @@ class CascadePipeline:
         if r.rid not in self._det_rid and r.rid not in self._rec_rid:
             self.other_results.append(r)
             return None
+        if r.detector is not None:           # a fused dispatch: final
+            crid = self._det_rid.pop(r.rid)
+            self._escalated += r.detector.escalated
+            return CascadeResult(crid, r.label, r.detector.escalated,
+                                 r.detector.label, r.detector.margin,
+                                 r.logits)
         if r.rid in self._det_rid:
             crid = self._det_rid.pop(r.rid)
             frame = self._frames.pop(r.rid)
@@ -270,84 +279,18 @@ class CascadePipeline:
                 srid = self.server.submit(self.recognizer, frame)
                 self._rec_rid[srid] = crid
 
-    def _step_fused(self, reqs) -> List[CascadeResult]:
-        """One fused dispatch: a detector batch through the in-kernel
-        cascade; every frame in it finalizes immediately (escalated
-        frames carry the recognizer's answer from the same kernel).
-        The dispatch is the server's ``serve.step`` span."""
-        srv = self.server
-        with srv.probe.span("serve.step", dispatch=srv._dispatches):
-            return self._run_fused(reqs)
-
-    def _run_fused(self, reqs) -> List[CascadeResult]:
-        srv = self.server
-        size = srv.batch
-        frames = srv.executor.pad_frames(reqs, srv._geom[self.detector],
-                                         size, srv._dispatches)
-        ctrl = interpreter.CascadePlan.margin_ctrl(self.margin, len(reqs))
-        dl, dlab, rl, rlab, queue, counts = self._fused["fn"](
-            self._fused["image"], frames, ctrl)
-        dl, dlab = np.asarray(dl), np.asarray(dlab)
-        rl, rlab = np.asarray(rl), np.asarray(rlab)
-        queue, counts = np.asarray(queue), np.asarray(counts)
-        esc, slots = int(counts[0]), int(counts[1])
-        # bill both phases at launch like ChipServer._launch: detector
-        # on every batch slot, recognizer on the slots the kernel
-        # actually computed (escalated + drain-chunk padding, from the
-        # kernel's own scalar report)
-        n = len(reqs)
-        srv._served[self.detector] += n
-        srv._padded[self.detector] += size - n
-        srv._vserved[self._det_variant] += n
-        srv._vpadded[self._det_variant] += size - n
-        srv._served[self.recognizer] += esc
-        srv._padded[self.recognizer] += slots - esc
-        srv._vserved[self._rec_variant] += esc
-        srv._vpadded[self._rec_variant] += slots - esc
-        srv._billed += size + slots
-        srv._dispatches += 1
-        # sequential phases: slot-weighted mean of the two occupancies
-        sd = srv.programs[self._det_variant].s
-        sr = srv.programs[self._rec_variant].s
-        srv._util_sum += (size / sd + slots / sr) / (size + slots)
-        self.fused_dispatches += 1
-        self._escalated += esc
-        rank = {int(p): k for k, p in enumerate(queue[:esc])}
-        out = []
-        for i, r in enumerate(reqs):
-            crid = self._det_rid.pop(r.rid)
-            m = self._margin(dl[i])
-            k = rank.get(i)
-            if k is None:
-                out.append(CascadeResult(
-                    rid=crid, label=int(dlab[i]), escalated=False,
-                    detector_label=int(dlab[i]), detector_margin=m,
-                    logits=dl[i]))
-            else:
-                out.append(CascadeResult(
-                    rid=crid, label=int(rlab[k]), escalated=True,
-                    detector_label=int(dlab[i]), detector_margin=m,
-                    logits=rl[k]))
-        return out
-
     def step(self) -> List[CascadeResult]:
-        """One dispatch; returns any cascade results it finalized.
+        """One server step; returns any cascade results it finalized.
 
-        Host mode: one server dispatch (escalating detector hits
-        finalize on a later recognizer dispatch).  Fused mode: one
-        detector batch through the in-kernel cascade, every frame in it
-        final; the server only steps for lanes outside the cascade.
-        [] when there was nothing to run."""
-        if self.fused:
-            reqs = self.server.queue.take(self.detector, self.server.batch)
-            if reqs:
-                return self._step_fused(reqs)
-            got = self.server.step()      # lanes outside the cascade
-            return [c for c in map(self._route, got) if c is not None]
+        Host mode: escalating detector hits finalize on a later
+        recognizer dispatch.  Fused mode: a detector dispatch finalizes
+        every frame in it.  [] when there was nothing to answer."""
         got = self.server.step()
         if not got and self._deferred:
             self._flush()                  # trailing partial batch
             got = self.server.step()
+        if got and got[0].detector is not None:
+            self.fused_dispatches += 1
         return [c for c in map(self._route, got) if c is not None]
 
     def drain(self) -> List[CascadeResult]:
@@ -412,7 +355,10 @@ class CascadePipeline:
         mid-stream report therefore never bills frames still queued or
         deferred, and the drain-time recognizer remainder's padding is
         billed exactly once — the escalation rate's denominator is the
-        detector frames served, not the padded slot count."""
+        detector frames served, not the padded slot count.  Fused
+        dispatches are billed when they finish (the recognizer's slots
+        are a count the kernel returns), so a mid-stream report bills
+        only the finished ones."""
         det_prog = self.server.programs[self._det_variant]
         rec_prog = self.server.programs[self._rec_variant]
         stats = self.server.stats()

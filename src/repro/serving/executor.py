@@ -32,6 +32,14 @@ here:
 Dispatches carry their own pad target (``Dispatch.batch``): a continuous
 policy's early-and-small launches pad only to their bucket size, not the
 full static batch, so the burned-slot bill shrinks with the window.
+
+A dispatch with a ``cascade`` route is the third kind beside single and
+composite: one detector batch through the fused cascade unit
+(:meth:`Executor.cascade_for`) with the route's margin as the kernel's
+control word.  It pipelines like the others; its bill depends on the
+counts the kernel returns, so :meth:`Executor.finish` hands them to the
+server's ``on_cascade`` books and counts ``cascade.escalated`` and
+``cascade.rec_slots``.
 """
 
 from __future__ import annotations
@@ -51,7 +59,8 @@ from repro.distributed import sharding
 from repro.kernels import cache as warmcache
 from repro.serving import telemetry
 from repro.serving.policy import Dispatch
-from repro.serving.queue import FrameRequest, FrameResult
+from repro.serving.queue import (DetectorAnswer, FrameRequest, FrameResult,
+                                 margins_of)
 
 
 class Executor:
@@ -70,8 +79,13 @@ class Executor:
                  megakernel: bool = False, prefetch: int = 1,
                  warm_start: bool = True,
                  clock: Callable[[], float] = time.perf_counter,
-                 probe: Optional[telemetry.Probe] = None):
+                 probe: Optional[telemetry.Probe] = None,
+                 on_cascade: Optional[Callable[[Dispatch, int, int],
+                                               None]] = None):
         self.batch = batch
+        # the owner's books for a finished cascade dispatch:
+        # (dispatch, escalated frames, recognizer slots computed)
+        self.on_cascade = on_cascade
         self.mesh = mesh
         self.prefetch = prefetch
         self.clock = clock
@@ -253,7 +267,13 @@ class Executor:
         handle (device arrays, not yet synced)."""
         size = dispatch.batch if dispatch.batch is not None else self.batch
         variants = tuple(ld.variant for ld in dispatch.lanes)
-        if dispatch.composite:
+        route = dispatch.cascade
+        if route is not None:
+            unit = self.cascade_for(variants[0], route.rec_variant,
+                                    positive_class=route.positive_class)
+            fn, art = unit["fn"], unit["image"]
+            variants += (route.rec_variant,)
+        elif dispatch.composite:
             comp = self.composite_for(variants)
             fn, art = comp["fn"], comp["image"]
         else:
@@ -266,13 +286,20 @@ class Executor:
             if self.mesh is not None:
                 frames = [sharding.scatter_frames(self.mesh, f)
                           for f in frames]
+            if route is not None:
+                ctrl = interpreter.CascadePlan.margin_ctrl(
+                    route.margin, len(dispatch.lanes[0].requests))
         if (variants, size) not in self._launched:
             self._launched.add((variants, size))
             probe.count("serve.compile", dispatch=index,
                         variants=",".join(variants), batch=size)
         with probe.span("serve.launch", dispatch=index):
-            logits, labels = fn(art, tuple(frames) if dispatch.composite
-                                else frames[0])
+            if route is not None:
+                dl, dlab, rl, rlab, queue, counts = fn(art, frames[0], ctrl)
+                logits, labels = (dl, rl), (dlab, rlab, queue, counts)
+            else:
+                logits, labels = fn(art, tuple(frames) if dispatch.composite
+                                    else frames[0])
         # the launch stamp: how long the oldest frame of the batch queued
         t_launch = probe.clock()
         oldest = min((ld.requests[0].t_submit for ld in dispatch.lanes
@@ -287,16 +314,20 @@ class Executor:
         """Sync an in-flight dispatch's device arrays to host numpy: the
         labels (``serve.wait``: the device's time and their transfer),
         then the logits (``serve.fetch``).  The background fetch thread
-        calls it without a probe and records nothing."""
-        many = handle["dispatch"].composite
+        calls it without a probe and records nothing.  A cascade's
+        "labels" are its labels, escalation queue and counts; its
+        "logits" both stages' logits.  Several arrays come over in one
+        ``device_get``, their copies overlapped."""
+        dispatch = handle["dispatch"]
+        many = dispatch.composite or dispatch.cascade is not None
         with (probe.span("serve.wait", dispatch=handle["index"])
               if probe is not None else contextlib.nullcontext()):
             labels = jax.block_until_ready(handle["labels"])
-            labels = (tuple(np.asarray(l) for l in labels) if many
+            labels = (tuple(jax.device_get(labels)) if many
                       else np.asarray(labels))
         with (probe.span("serve.fetch", dispatch=handle["index"])
               if probe is not None else contextlib.nullcontext()):
-            logits = (tuple(np.asarray(l) for l in handle["logits"]) if many
+            logits = (tuple(jax.device_get(handle["logits"])) if many
                       else np.asarray(handle["logits"]))
         return logits, labels
 
@@ -308,7 +339,38 @@ class Executor:
         else:
             logits, labels = self.materialize(handle, self.probe)
         with self.probe.span("serve.finish", dispatch=handle["index"]):
+            if handle["dispatch"].cascade is not None:
+                return self._cascade_results(handle, logits, labels)
             return self._results(handle, logits, labels)
+
+    def _cascade_results(self, handle, logits, labels) -> List[FrameResult]:
+        """A finished cascade dispatch: its books (from the kernel's
+        counts) and one result per real frame, answered by the
+        recognizer where the frame escalated, else by the detector."""
+        dispatch: Dispatch = handle["dispatch"]
+        index = handle["index"]
+        t_done = self.clock()
+        dl, rl = logits
+        dlab, rlab, queue, counts = labels
+        esc, slots = int(counts[0]), int(counts[1])
+        self.probe.count("cascade.escalated", esc, dispatch=index)
+        self.probe.count("cascade.rec_slots", slots, dispatch=index)
+        if self.on_cascade is not None:
+            self.on_cascade(dispatch, esc, slots)
+        rank = {p: k for k, p in enumerate(queue[:esc].tolist())}
+        margins = margins_of(dl, dispatch.cascade.positive_class).tolist()
+        dlab, rlab = dlab.tolist(), rlab.tolist()
+        drows, rrows = list(dl), list(rl)
+        ld, = dispatch.lanes
+        out = []
+        for i, r in enumerate(ld.requests):
+            k = rank.get(i)
+            det = DetectorAnswer(dlab[i], drows[i], margins[i], k is not None)
+            label, lg = ((dlab[i], drows[i]) if k is None
+                         else (rlab[k], rrows[k]))
+            out.append(FrameResult(r.rid, ld.lane, label, lg, index,
+                                   ld.variant, r.t_submit, t_done, det))
+        return out
 
     def _results(self, handle, logits, labels) -> List[FrameResult]:
         dispatch: Dispatch = handle["dispatch"]

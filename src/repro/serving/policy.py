@@ -73,14 +73,29 @@ class LaneDispatch:
 
 
 @dataclasses.dataclass(frozen=True)
+class CascadeRoute:
+    """A lane served as the detector of a fused cascade: every dispatch
+    of it also runs ``rec_variant`` (the ``recognizer`` lane's program)
+    over the frames whose detector margin reaches ``margin``, inside the
+    same kernel (``Executor.cascade_for``)."""
+    recognizer: str
+    rec_variant: str
+    positive_class: int = 1
+    margin: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class Dispatch:
     """A policy decision: one batch per member lane, executed as one
     array pass (solo for a single lane, a shared-array composite for
-    several).  ``batch`` is this dispatch's pad target — every member
-    lane's pull is padded to it; ``None`` means the server's static
-    batch (the pre-continuous behaviour)."""
+    several, or a fused cascade when ``cascade`` is set: one detector
+    lane whose escalations the recognizer answers in the same pass).
+    ``batch`` is this dispatch's pad target — every member lane's pull
+    is padded to it; ``None`` means the server's static batch (the
+    pre-continuous behaviour)."""
     lanes: Tuple[LaneDispatch, ...]
     batch: Optional[int] = None
+    cascade: Optional[CascadeRoute] = None
 
     @property
     def composite(self) -> bool:
@@ -116,6 +131,7 @@ class DispatchPolicy:
         self.ctx: Optional[PolicyContext] = None
         self.variant_dispatches: Dict[str, int] = {}
         self.flush = False              # drain mode: never hold frames back
+        self.cascades: Dict[str, CascadeRoute] = {}   # detector lane -> route
 
     def bind(self, ctx: PolicyContext) -> None:
         self.ctx = ctx
@@ -131,6 +147,11 @@ class DispatchPolicy:
         rather than wait for its window/deadline conditions."""
         self.flush = flush
 
+    def set_cascade(self, lane: str, route: CascadeRoute) -> None:
+        """Serve ``lane`` as a fused cascade's detector: each dispatch of
+        it carries ``route``.  The lane always dispatches solo."""
+        self.cascades[lane] = route
+
     def select(self, queue: FrameQueue) -> Optional[Dispatch]:
         raise NotImplementedError
 
@@ -143,9 +164,14 @@ class DispatchPolicy:
         return self.select(queue)
 
     def _count(self, dispatch: Dispatch) -> Dispatch:
+        """Count the dispatch's variants; a cascade detector lane's
+        dispatch leaves carrying its route."""
         for ld in dispatch.lanes:
             self.variant_dispatches[ld.variant] = (
                 self.variant_dispatches.get(ld.variant, 0) + 1)
+        route = self.cascades.get(dispatch.lanes[0].lane)
+        if route is not None and not dispatch.composite:
+            return dataclasses.replace(dispatch, cascade=route)
         return dispatch
 
     def variant_order(self, lane: str) -> Tuple[str, ...]:
@@ -318,11 +344,12 @@ class OperatingPointPolicy(DispatchPolicy):
         spent += size * self._e1[head]
         time += size * self._t1[head]
 
-        if self.shared and occ < 1.0 - 1e-9:
+        if self.shared and occ < 1.0 - 1e-9 and lane not in self.cascades:
             # riders: other backlogged lanes whose chosen variants fill
             # the freed sub-array lanes — commit only on an exact tiling
             for other in queue.rr_lanes():
-                if other == lane or not queue.pending(other):
+                if (other == lane or not queue.pending(other)
+                        or other in self.cascades):
                     continue
                 v = self._choose(other, queue.pending(other), size,
                                  spent, time)
@@ -420,6 +447,10 @@ class ContinuousPolicy(DispatchPolicy):
     def set_flush(self, flush: bool) -> None:
         super().set_flush(flush)
         self.inner.set_flush(flush)
+
+    def set_cascade(self, lane: str, route: CascadeRoute) -> None:
+        super().set_cascade(lane, route)
+        self.inner.set_cascade(lane, route)     # it builds the dispatches
 
     def variant_order(self, lane: str) -> Tuple[str, ...]:
         return self.inner.variant_order(lane)

@@ -52,6 +52,25 @@ class FrameRequest:
                               # unstamped, latency accounting skips it)
 
 
+def margins_of(logits, positive_class: int = 1) -> np.ndarray:
+    """Vectorized escalation margins: positive-class logit minus the best
+    competing logit, float64, one per row of ``logits``."""
+    lg = np.asarray(logits, dtype=np.float64)
+    pos = lg[:, positive_class]
+    rest = np.delete(lg, positive_class, axis=1).max(axis=1)
+    return pos - rest
+
+
+@dataclasses.dataclass(frozen=True)
+class DetectorAnswer:
+    """What a fused cascade's detector stage said about one frame."""
+    label: int
+    logits: np.ndarray
+    margin: float             # positive-class logit less the best other
+    escalated: bool           # the margin reached the threshold: the
+                              # recognizer's answer is the frame's result
+
+
 @dataclasses.dataclass(frozen=True)
 class FrameResult:
     rid: int
@@ -64,6 +83,9 @@ class FrameResult:
                               # controller-chosen operating point)
     t_submit: float = 0.0     # admission timestamp carried from the request
     t_done: float = 0.0       # label available on the host (same clock)
+    detector: Optional[DetectorAnswer] = None  # fused cascade dispatches:
+                              # the detector stage's answer (label and
+                              # logits above are the answering stage's)
 
     @property
     def latency_s(self) -> float:

@@ -40,9 +40,9 @@ import numpy as np
 from repro.core.chip import energy, interpreter, isa
 from repro.serving import telemetry as telemetry_mod
 from repro.serving.executor import Executor
-from repro.serving.policy import (ContinuousPolicy, DispatchPolicy,
-                                  OperatingPointPolicy, PolicyContext,
-                                  StaticPolicy)
+from repro.serving.policy import (CascadeRoute, ContinuousPolicy, Dispatch,
+                                  DispatchPolicy, OperatingPointPolicy,
+                                  PolicyContext, StaticPolicy)
 from repro.serving.queue import (FrameQueue, FrameRequest, FrameResult,
                                  plan_shared_groups)
 
@@ -179,7 +179,8 @@ class ChipServer:
                                  interpret=interpret, megakernel=megakernel,
                                  prefetch=self.prefetch,
                                  warm_start=warm_start, clock=clock,
-                                 probe=self.probe)
+                                 probe=self.probe,
+                                 on_cascade=self._book_cascade)
         self.plans = self.executor.plans
         self.artifacts = self.executor.artifacts
         self.queue = FrameQueue(self._lanes)
@@ -294,12 +295,35 @@ class ChipServer:
     def submit_many(self, program: str, frames) -> List[int]:
         return [self.submit(program, f) for f in frames]
 
+    def bind_cascade(self, detector: str, recognizer: str, *,
+                     positive_class: int = 1, margin: float = 0.0) -> None:
+        """Serve lane ``detector`` as a fused cascade: each dispatch of it
+        runs the detector over the batch and, in the same kernel, lane
+        ``recognizer``'s program over the frames whose margin reaches
+        ``margin`` (``Executor.cascade_for``; compiled here, like the
+        composites of shared groups).  Binding again replaces the route;
+        dispatches already launched keep theirs."""
+        for lane in (detector, recognizer):
+            if lane in self.policy.ctx.groups:
+                raise ValueError(
+                    f"cascade stage {lane!r} is in a shared-array group; a "
+                    "fused cascade dispatches its detector lane solo")
+        det_v = self._lane_variants[detector][0]
+        rec_v = self._lane_variants[recognizer][0]
+        self.executor.cascade_for(det_v, rec_v,
+                                  positive_class=positive_class)
+        self.policy.set_cascade(detector, CascadeRoute(
+            recognizer=recognizer, rec_variant=rec_v,
+            positive_class=positive_class, margin=margin))
+
     # -- dispatch side ------------------------------------------------------
 
     def _launch(self) -> Optional[Dict[str, Any]]:
         """Consult the policy for the next dispatch, run it, and bill it.
         Serving counters are billed at launch — the energy is burned the
-        moment the batch hits the array, synced or not."""
+        moment the batch hits the array, synced or not — except for a
+        fused cascade, whose recognizer bill is a count the kernel
+        returns: it is billed when it finishes (:meth:`_book_cascade`)."""
         index = self._dispatches
         with self.probe.span("serve.select", dispatch=index):
             dispatch = self.policy.select(self.queue)
@@ -307,6 +331,8 @@ class ChipServer:
             return None
         self._dispatches += 1
         handle = self.executor.launch(dispatch, index)
+        if dispatch.cascade is not None:
+            return handle
         with self.probe.span("serve.finish", dispatch=index):
             size = (dispatch.batch if dispatch.batch is not None
                     else self.batch)
@@ -327,6 +353,29 @@ class ChipServer:
                 self._util_sum += 1.0 / self.programs[
                     dispatch.lanes[0].variant].s
         return handle
+
+    def _book_cascade(self, dispatch: Dispatch, escalated: int,
+                      slots: int) -> None:
+        """Bill a finished cascade dispatch: the detector on every batch
+        slot, the recognizer on the ``slots`` the kernel computed (the
+        ``escalated`` frames and the drain chunks' padding)."""
+        ld, = dispatch.lanes
+        route = dispatch.cascade
+        size = dispatch.batch if dispatch.batch is not None else self.batch
+        n = len(ld.requests)
+        for lane, variant, served, burned in (
+                (ld.lane, ld.variant, n, size - n),
+                (route.recognizer, route.rec_variant, escalated,
+                 slots - escalated)):
+            self._served[lane] += served
+            self._padded[lane] += burned
+            self._vserved[variant] += served
+            self._vpadded[variant] += burned
+        self._billed += size + slots
+        # sequential phases: slot-weighted mean of the two occupancies
+        sd = self.programs[ld.variant].s
+        sr = self.programs[route.rec_variant].s
+        self._util_sum += (size / sd + slots / sr) / (size + slots)
 
     def step(self) -> List[FrameResult]:
         """One dispatch: pull a static batch, run its program(s), return
@@ -421,7 +470,8 @@ class ChipServer:
         preserved (in-flight dispatches oldest-first, then the queued
         FIFO).  The energy already billed for abandoned in-flight
         dispatches stays billed — it was burned the moment the batch hit
-        the array — so this replica's ``billed == served + padded``
+        the array (a fused cascade bills at finish, so an abandoned one
+        stays unbilled) — so this replica's ``billed == served + padded``
         ledger stays consistent; the migrated frames are re-billed by
         whoever serves them.  The server is unusable afterwards."""
         orphans: Dict[str, List[FrameRequest]] = {

@@ -39,6 +39,10 @@ Names (``docs/serving.md``, "Telemetry"):
 * ``serve.ahead`` (counter, no events) — dispatches launched while an
   earlier dispatch of the same server was still in flight: over the
   ``serve.launch`` count, the share of dispatches the pipeline overlapped;
+* ``cascade.escalated``, ``cascade.rec_slots`` (counters, with the
+  dispatch index) — per finished fused cascade dispatch, the frames the
+  kernel escalated and the recognizer slots it computed (the excess over
+  the escalated frames is drain-chunk padding);
 * ``fleet.step``, ``fleet.fail``, ``fleet.replace`` — one fleet tick; a
   kill's harvest and requeue; building the replacement replica.
 """
