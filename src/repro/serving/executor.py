@@ -7,14 +7,21 @@ here:
 * **launch** — pad each member lane's pull to the static batch (the
   always-on pipeline never idles; short lanes pad with the last real
   frame, empty lanes with zeros), scatter over the serving mesh if one
-  is bound, and run the member's jit'd serve function.  A multi-lane
+  is bound, and run the member's jit'd serve function, then ask for
+  every output's device-to-host copy at once (``copy_to_host_async``):
+  the runtime starts each copy as soon as the kernel that writes it
+  ends, so the host finds it done or under way when it comes back for
+  the results, instead of starting it then.  A multi-lane
   dispatch runs as ONE shared-array composite ``pallas_call``
   (``interpreter.pack_programs``): composites are compiled lazily per
   ordered variant tuple and cached, so both admission-time groups
   (static policy) and per-dispatch tilings (operating-point controller
   downshifts) hit the same compile cache.
 * **materialize / finish** — sync a dispatch's device arrays to host
-  numpy and unpack them into per-request :class:`FrameResult`s.
+  numpy and unpack them into per-request :class:`FrameResult`s.  A
+  dispatch whose outputs are all computed when the host comes to
+  finish it counts ``serve.ready``: its copies could run wholly while
+  the host did other work.
 * **depth-k prefetch pipeline** — :meth:`step` keeps up to ``prefetch``
   dispatches in flight before blocking on the oldest one, with finished
   results fetched to host memory by a background thread; the policy is
@@ -300,6 +307,8 @@ class Executor:
             else:
                 logits, labels = fn(art, tuple(frames) if dispatch.composite
                                     else frames[0])
+            for out in jax.tree_util.tree_leaves((logits, labels)):
+                out.copy_to_host_async()
         # the launch stamp: how long the oldest frame of the batch queued
         t_launch = probe.clock()
         oldest = min((ld.requests[0].t_submit for ld in dispatch.lanes
@@ -312,12 +321,12 @@ class Executor:
     @staticmethod
     def materialize(handle: Dict[str, Any], probe=None):
         """Sync an in-flight dispatch's device arrays to host numpy: the
-        labels (``serve.wait``: the device's time and their transfer),
-        then the logits (``serve.fetch``).  The background fetch thread
-        calls it without a probe and records nothing.  A cascade's
-        "labels" are its labels, escalation queue and counts; its
-        "logits" both stages' logits.  Several arrays come over in one
-        ``device_get``, their copies overlapped."""
+        labels (``serve.wait``: what is left of the device's time and of
+        their transfer), then the logits (``serve.fetch``: what is left
+        of theirs).  Both copies were asked for at launch.  The
+        background fetch thread calls it without a probe and records
+        nothing.  A cascade's "labels" are its labels, escalation queue
+        and counts; its "logits" both stages' logits."""
         dispatch = handle["dispatch"]
         many = dispatch.composite or dispatch.cascade is not None
         with (probe.span("serve.wait", dispatch=handle["index"])
@@ -332,7 +341,12 @@ class Executor:
         return logits, labels
 
     def finish(self, handle: Dict[str, Any]) -> List[FrameResult]:
-        """Block on an in-flight dispatch and materialize its results."""
+        """Block on an in-flight dispatch and materialize its results.
+        Counts ``serve.ready`` first if the device has already computed
+        every output of the dispatch."""
+        if all(out.is_ready() for out in jax.tree_util.tree_leaves(
+                (handle["logits"], handle["labels"]))):
+            self.probe.count("serve.ready", dispatch=handle["index"])
         if "future" in handle:
             with self.probe.span("serve.wait", dispatch=handle["index"]):
                 logits, labels = handle["future"].result()

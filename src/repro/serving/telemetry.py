@@ -31,14 +31,20 @@ Names (``docs/serving.md``, "Telemetry"):
 * ``serve.step`` — one ``ChipServer.step`` as its caller sees it, and
   inside it ``serve.select`` (policy and queue take), ``serve.stack``
   (stacking and padding the batch), ``serve.put`` (host-to-device copy),
-  ``serve.launch`` (the jitted call; a compile shows here),
-  ``serve.wait`` (device time plus the label transfer), ``serve.fetch``
-  (logits to host) and ``serve.finish`` (results and the server's books);
+  ``serve.launch`` (the jitted call and the request for its outputs'
+  host copies; a compile shows here), ``serve.wait`` (what remains of
+  the device time and of the label transfer), ``serve.fetch`` (what
+  remains of the logits' transfer) and ``serve.finish`` (results and
+  the server's books);
 * ``serve.compile`` (counter, with the dispatch index) — launches of a
   (variant, batch size) the executor has not run before;
 * ``serve.ahead`` (counter, no events) — dispatches launched while an
   earlier dispatch of the same server was still in flight: over the
   ``serve.launch`` count, the share of dispatches the pipeline overlapped;
+* ``serve.ready`` (counter, with the dispatch index) — dispatches whose
+  outputs the device had all computed when the host came to finish
+  them: over the ``serve.launch`` count, the share whose transfers,
+  started at launch, could run wholly while the host did other work;
 * ``cascade.escalated``, ``cascade.rec_slots`` (counters, with the
   dispatch index) — per finished fused cascade dispatch, the frames the
   kernel escalated and the recognizer slots it computed (the excess over

@@ -152,14 +152,31 @@ def test_failover_zero_loss_bit_exact_mid_replay(mnist_setup):
     assert st.total_served == n + st.refired_frames
 
 
+@pytest.fixture
+def host_copies(monkeypatch):
+    """Every device array asked for a host copy, once per request (held,
+    so identity stays unique)."""
+    impl = type(jnp.zeros(()))
+    asked = []
+    copy = impl.copy_to_host_async
+
+    def record(self):
+        asked.append(self)
+        return copy(self)
+    monkeypatch.setattr(impl, "copy_to_host_async", record)
+    return asked
+
+
 @pytest.mark.parametrize("prefetch", [0, 1])
-def test_migration_preserves_per_lane_order(mnist_setup, prefetch):
+def test_migration_preserves_per_lane_order(mnist_setup, prefetch,
+                                            host_copies):
     """Migrated frames enter the survivor's lane front: they keep their
     own relative order and serve before anything routed to the survivor
     after the failure; the survivor's own frames also stay in order.
-    At depth 1 each replica already has its next dispatch in flight:
-    the victim's is migrated, the survivor's is answered first."""
-    program, packed, frames, _ = mnist_setup
+    At depth 1 each replica already has its next dispatch in flight,
+    its host copies already asked for: the victim's is migrated, the
+    survivor's is answered first.  Every label matches the oracle."""
+    program, packed, frames, labels = mnist_setup
     vc = VirtualClock()
     fleet = _fleet(program, packed, vc, batch=2, replace=False,
                    prefetch=prefetch)
@@ -168,6 +185,13 @@ def test_migration_preserves_per_lane_order(mnist_setup, prefetch):
         fleet.submit("mnist5", f)
     first = fleet.step()             # one dispatch on each replica
     assert len(first) == 4
+    inflight = list(fleet.replicas["host0"].executor._inflight)
+    assert len(inflight) == prefetch
+    for handle in inflight:          # launched, its copies under way
+        outs = jax.tree_util.tree_leaves((handle["logits"],
+                                          handle["labels"]))
+        assert [sum(a is o for a in host_copies) for o in outs] == \
+            [1] * len(outs)
     orphans = fleet.fail("host0")
     migrated = [r.rid for r in orphans["mnist5"]]
     assert migrated == [4, 5]        # host0's unserved frames, in order
@@ -185,6 +209,8 @@ def test_migration_preserves_per_lane_order(mnist_setup, prefetch):
     assert served_after.index(6) < served_after.index(7)
     assert max(served_after.index(r) for r in [4, 5, 6, 7]) < \
         min(served_after.index(r) for r in post)
+    for r in first + results:
+        assert r.label == labels[r.rid]
 
 
 def test_fail_last_replica_raises(mnist_setup):

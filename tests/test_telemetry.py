@@ -6,7 +6,8 @@ counters, and what the servers record into them.
   seconds, percentiles as numpy computes them, wrap-around and reset;
 * the servers: a frozen ``VirtualClock`` records zeros, ``serve.compile``
   counts each new (variant, batch size) once, ``serve.ahead`` each
-  launch made with a dispatch in flight, a fleet's kill records
+  launch made with a dispatch in flight, ``serve.ready`` at most once
+  a dispatch, with its index, a fleet's kill records
   ``fleet.fail``/``fleet.replace`` under replica names, and a profiler
   trace holds the spans with their dispatch ids.
 """
@@ -228,9 +229,10 @@ def test_compile_counts_once_per_new_shape(mnist):
         server.drain()
     snap = rec.snapshot()
     assert snap["counters"]["serve.compile"] == 2
-    assert sorted(e["variants"] for e in snap["events"]) == ["a", "b"]
-    assert sorted(e["dispatch"] for e in snap["events"]) == [0, 1]
-    assert all(e["batch"] == 4 for e in snap["events"])
+    compiles = [e for e in snap["events"] if e["name"] == "serve.compile"]
+    assert sorted(e["variants"] for e in compiles) == ["a", "b"]
+    assert sorted(e["dispatch"] for e in compiles) == [0, 1]
+    assert all(e["batch"] == 4 for e in compiles)
 
 
 @pytest.mark.parametrize("prefetch", [0, 1, 2])
@@ -251,6 +253,28 @@ def test_ahead_counts_overlapped_launches(mnist, prefetch):
     assert snap["counters"].get("serve.ahead", 0) == (
         dispatches - 1 if prefetch else 0)
     assert all(e["name"] != "serve.ahead" for e in snap["events"])
+
+
+@pytest.mark.parametrize("prefetch", [0, 1])
+def test_ready_counts_dispatches_computed_before_finish(mnist, prefetch):
+    """``serve.ready`` counts a dispatch whose outputs were all computed
+    when the host came to finish it, with its dispatch index: at most
+    once a dispatch, so never more often than ``serve.launch``."""
+    program, packed, frames = mnist
+    rec = telemetry.Recorder()
+    server = ChipServer({"m": program}, {"m": packed}, batch=4,
+                        interpret=True, telemetry=rec, prefetch=prefetch)
+    for f in frames:
+        server.submit("m", f)
+    assert len(server.drain()) == len(frames)
+    snap = rec.snapshot()
+    launches = snap["spans"]["serve.launch"]["count"]
+    ready = [e for e in snap["events"] if e["name"] == "serve.ready"]
+    assert snap["counters"].get("serve.ready", 0) == len(ready) <= launches
+    ids = [e["dispatch"] for e in ready]
+    assert len(set(ids)) == len(ids)
+    assert set(ids) <= set(range(launches))
+    assert all(e["replica"] == "server" for e in ready)
 
 
 def test_latency_trace_expands_compact_rows(mnist):
