@@ -3,7 +3,6 @@
   fig4_layer_perf    Fig. 4  per-layer core GOPS / TOPS/W
   fig5_i2l           Fig. 5  I2L energy/throughput/power vs S
   table1_comparison  Table 1 cross-chip comparison + advantage ratios
-  kernel_microbench  packed XNOR-popcount vs float path (+ allclose)
   roofline_report    40-cell dry-run roofline table (needs dryrun JSONs)
 
 Each prints human tables plus a ``CSV,name,us_per_call,derived`` line.
@@ -16,13 +15,12 @@ import sys
 
 
 def main() -> None:
-    from benchmarks import (fig4_layer_perf, fig5_i2l, kernel_microbench,
-                            roofline_report, table1_comparison)
+    from benchmarks import (fig4_layer_perf, fig5_i2l, roofline_report,
+                            table1_comparison)
     results = {}
     for name, mod in [("fig4_layer_perf", fig4_layer_perf),
                       ("fig5_i2l", fig5_i2l),
                       ("table1_comparison", table1_comparison),
-                      ("kernel_microbench", kernel_microbench),
                       ("roofline_report", roofline_report)]:
         try:
             results[name] = bool(mod.run())

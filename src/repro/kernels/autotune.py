@@ -29,8 +29,8 @@ shape and datapath).  This module owns that choice:
   to the cold-cache defaults instead of mis-steering the new kernel —
   a stale ``BENCH_autotune.json`` is never an error, just cold.
 
-The bench job ships the cache next to ``BENCH_kernels.json`` so CI (and
-the next session) start warm.
+The committed ``BENCH_autotune.json`` holds CPU entries only, so a run
+on the chip finds no entry for its backend and takes the cold defaults.
 """
 
 from __future__ import annotations

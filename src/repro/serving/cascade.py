@@ -160,15 +160,15 @@ class CascadePipeline:
             if lane not in server.queue.lanes:
                 raise KeyError(f"lane {lane!r} not resident on the server "
                                f"(have {sorted(server.queue.lanes)})")
-            if len(server._lane_variants[lane]) > 1:
+            if len(server.lane_variants(lane)) > 1:
                 raise ValueError(
                     f"cascade stage {lane!r} is a program family; cascade "
                     "stages must be single-variant lanes (the energy bill "
                     "is per stage program)")
         if detector == recognizer:
             raise ValueError("detector and recognizer must be distinct lanes")
-        gd = server._geom[detector]
-        gr = server._geom[recognizer]
+        gd = server.lane_geometry(detector)
+        gr = server.lane_geometry(recognizer)
         if gd != gr:
             raise ValueError(
                 f"cascade stages disagree on frame geometry: "
@@ -179,8 +179,8 @@ class CascadePipeline:
         self.positive_class = positive_class
         self.fused = fused
         self.margin = margin
-        self._det_variant = server._lane_variants[detector][0]
-        self._rec_variant = server._lane_variants[recognizer][0]
+        self._det_variant = server.lane_variants(detector)[0]
+        self._rec_variant = server.lane_variants(recognizer)[0]
         self.fused_dispatches = 0
         self._next_rid = 0
         self._frames: Dict[int, np.ndarray] = {}   # srid -> frame (det stage)
@@ -334,13 +334,13 @@ class CascadePipeline:
         """Calibrate ``self.margin`` on a held-out labelled split via
         :func:`calibrate_margin` (the pipeline's own detector program
         and artifact); returns — and adopts — the chosen margin."""
-        ex = self.server.executor
+        artifact, interpret = self.server.executor.calibration_inputs(
+            self._det_variant)
         self.margin = calibrate_margin(
             frames, labels, target_recall,
             detector=self.server.programs[self._det_variant],
-            artifact=ex._raw_artifacts[self._det_variant],
-            positive_class=self.positive_class,
-            interpret=ex._interpret)
+            artifact=artifact, positive_class=self.positive_class,
+            interpret=interpret)
         return self.margin
 
     def report(self, include_padding: bool = True) -> energy.CascadeReport:

@@ -23,18 +23,16 @@ here:
   finish it counts ``serve.ready``: its copies could run wholly while
   the host did other work.
 * **depth-k prefetch pipeline** — :meth:`step` keeps up to ``prefetch``
-  dispatches in flight before blocking on the oldest one, with finished
-  results fetched to host memory by a background thread; the policy is
+  dispatches in flight before blocking on the oldest one; the policy is
   still consulted in exactly the synchronous order, so pipelining never
   changes the schedule (property-tested).  Depth 1, the server's
   default, launches the next ready dispatch before blocking on the
   current one, so the host path of dispatch N+1 runs while the device
-  computes N; with nothing else ready the step is synchronous.  At
-  depth 1 the background thread is skipped: with a single in-flight
-  handle the consumer pops it immediately, so a fetch thread adds
-  handoff overhead without any overlap to win (the BENCH
-  prefetch-anomaly fix).  Each launch made while an earlier dispatch is
-  still in flight counts ``serve.ahead``.
+  computes N; with nothing else ready the step is synchronous.  Every
+  dispatch is materialized on the caller's thread: the copies started
+  at launch are what overlaps the host, so the executor runs no thread
+  of its own.  Each launch made while an earlier dispatch is still in
+  flight counts ``serve.ahead``.
 
 Dispatches carry their own pad target (``Dispatch.batch``): a continuous
 policy's early-and-small launches pad only to their bucket size, not the
@@ -52,8 +50,6 @@ server's ``on_cascade`` books and counts ``cascade.escalated`` and
 from __future__ import annotations
 
 import collections
-import concurrent.futures
-import contextlib
 import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
@@ -133,13 +129,6 @@ class Executor:
         self._cascades: Dict[Tuple[str, str, int], Dict[str, Any]] = {}
         self._deltas: Dict[Tuple[str, Optional[int], int], Dict[str, Any]] = {}
         self._inflight: collections.deque = collections.deque()
-        # background fetch only pays off at depth >= 2: with one handle
-        # in flight the consumer blocks on it immediately, so a thread
-        # handoff is pure overhead (see module docstring)
-        self._fetch_pool: Optional[concurrent.futures.ThreadPoolExecutor] = (
-            concurrent.futures.ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="serve-fetch")
-            if self.prefetch >= 2 else None)
 
     def _serve_fn(self, plan, progs: Tuple[isa.Program, ...],
                   kind: str = "serve", **extra):
@@ -168,6 +157,11 @@ class Executor:
 
     def geometry(self, variant: str) -> Tuple[int, int, int]:
         return self._geom[variant]
+
+    def calibration_inputs(self, variant: str) -> Tuple[Any, Optional[bool]]:
+        """What an offline calibration of ``variant`` runs on: its
+        admission-time artifact and this executor's interpret mode."""
+        return self._raw_artifacts[variant], self._interpret
 
     # -- composite compilation ---------------------------------------------
 
@@ -318,24 +312,20 @@ class Executor:
         return dict(dispatch=dispatch, index=index, logits=logits,
                     labels=labels)
 
-    @staticmethod
-    def materialize(handle: Dict[str, Any], probe=None):
+    def materialize(self, handle: Dict[str, Any]):
         """Sync an in-flight dispatch's device arrays to host numpy: the
         labels (``serve.wait``: what is left of the device's time and of
         their transfer), then the logits (``serve.fetch``: what is left
-        of theirs).  Both copies were asked for at launch.  The
-        background fetch thread calls it without a probe and records
-        nothing.  A cascade's "labels" are its labels, escalation queue
-        and counts; its "logits" both stages' logits."""
+        of theirs).  Both copies were asked for at launch.  A cascade's
+        "labels" are its labels, escalation queue and counts; its
+        "logits" both stages' logits."""
         dispatch = handle["dispatch"]
         many = dispatch.composite or dispatch.cascade is not None
-        with (probe.span("serve.wait", dispatch=handle["index"])
-              if probe is not None else contextlib.nullcontext()):
+        with self.probe.span("serve.wait", dispatch=handle["index"]):
             labels = jax.block_until_ready(handle["labels"])
             labels = (tuple(jax.device_get(labels)) if many
                       else np.asarray(labels))
-        with (probe.span("serve.fetch", dispatch=handle["index"])
-              if probe is not None else contextlib.nullcontext()):
+        with self.probe.span("serve.fetch", dispatch=handle["index"]):
             logits = (tuple(jax.device_get(handle["logits"])) if many
                       else np.asarray(handle["logits"]))
         return logits, labels
@@ -347,11 +337,7 @@ class Executor:
         if all(out.is_ready() for out in jax.tree_util.tree_leaves(
                 (handle["logits"], handle["labels"]))):
             self.probe.count("serve.ready", dispatch=handle["index"])
-        if "future" in handle:
-            with self.probe.span("serve.wait", dispatch=handle["index"]):
-                logits, labels = handle["future"].result()
-        else:
-            logits, labels = self.materialize(handle, self.probe)
+        logits, labels = self.materialize(handle)
         with self.probe.span("serve.finish", dispatch=handle["index"]):
             if handle["dispatch"].cascade is not None:
                 return self._cascade_results(handle, logits, labels)
@@ -418,9 +404,9 @@ class Executor:
     def _fill(self, launch_fn: Callable[[], Optional[Dict[str, Any]]],
               busy: bool = False) -> None:
         """Launch dispatches until ``prefetch`` are in flight (or the
-        policy has nothing ready), handing each to the background fetch
-        thread.  ``busy``: the caller holds a popped dispatch that is
-        still running, so every launch here runs ahead of it."""
+        policy has nothing ready).  ``busy``: the caller holds a popped
+        dispatch that is still running, so every launch here runs ahead
+        of it."""
         while len(self._inflight) < self.prefetch:
             ahead = busy or bool(self._inflight)
             handle = launch_fn()
@@ -428,9 +414,6 @@ class Executor:
                 return
             if ahead:
                 self.probe.count("serve.ahead")
-            if self._fetch_pool is not None:
-                handle["future"] = self._fetch_pool.submit(
-                    self.materialize, handle)
             self._inflight.append(handle)
 
     def step(self, launch_fn: Callable[[], Optional[Dict[str, Any]]]
@@ -453,33 +436,17 @@ class Executor:
         materializing results and hand back the orphaned requests,
         oldest dispatch first (the fleet re-enqueues them, in order, at
         the front of a survivor's lanes).  Device work already launched
-        is abandoned — its energy was billed at launch and is genuinely
-        burned, exactly like a chip losing power mid-frame."""
+        is abandoned; what the server billed for it at launch stays
+        billed, exactly like a chip losing power mid-frame (a cascade,
+        billed only when it finishes, stays unbilled)."""
         orphans: List[FrameRequest] = []
         while self._inflight:
-            handle = self._inflight.popleft()
-            fut = handle.get("future")
-            if fut is not None:
-                fut.cancel()
-            for ld in handle["dispatch"].lanes:
+            for ld in self._inflight.popleft()["dispatch"].lanes:
                 orphans.extend(ld.requests)
-        if self._fetch_pool is not None:
-            self._fetch_pool.shutdown(wait=False, cancel_futures=True)
-            self._fetch_pool = None
         return orphans
 
     def close(self) -> None:
-        """Release the background fetch thread, syncing (and discarding)
-        any in-flight dispatches; safe to call more than once."""
+        """Sync (and discard) any in-flight dispatches; safe to call
+        more than once."""
         while self._inflight:
             self.finish(self._inflight.popleft())
-        if self._fetch_pool is not None:
-            self._fetch_pool.shutdown(wait=True)
-            self._fetch_pool = None
-
-    def __del__(self):  # pragma: no cover - interpreter-exit ordering
-        try:
-            if getattr(self, "_fetch_pool", None) is not None:
-                self._fetch_pool.shutdown(wait=False)
-        except Exception:
-            pass

@@ -195,8 +195,7 @@ class ServeFleet:
 
     @property
     def total_served(self) -> int:
-        return sum(sum(s._served.values())
-                   for s in list(self.replicas.values())
+        return sum(s.total_served for s in list(self.replicas.values())
                    + list(self._dead.values()))
 
     def _route(self, lane: str) -> str:
@@ -377,10 +376,10 @@ class ServeFleet:
                 padded[lane] += st.padded.get(lane, 0)
             dispatches += st.dispatches
             wall += st.host_wall_s
-            billed += sum(st.served.values()) + sum(st.padded.values())
+            billed += st.billed
             energy_uj += st.energy_uj
         for server in list(self.replicas.values()) + list(self._dead.values()):
-            lats.append(server._latency_s() * 1e3)
+            lats.append(server.latency_s() * 1e3)
         lats = np.concatenate(lats) if lats else np.zeros(0)
         if len(lats):
             p50, p95, p99 = np.percentile(lats, [50, 95, 99])

@@ -163,12 +163,16 @@ class DispatchPolicy:
         ignores ``size`` and keeps the static batch."""
         return self.select(queue)
 
+    def count_variant(self, variant: str) -> None:
+        """Count one dispatch that ``variant`` ran."""
+        self.variant_dispatches[variant] = (
+            self.variant_dispatches.get(variant, 0) + 1)
+
     def _count(self, dispatch: Dispatch) -> Dispatch:
         """Count the dispatch's variants; a cascade detector lane's
         dispatch leaves carrying its route."""
         for ld in dispatch.lanes:
-            self.variant_dispatches[ld.variant] = (
-                self.variant_dispatches.get(ld.variant, 0) + 1)
+            self.count_variant(ld.variant)
         route = self.cascades.get(dispatch.lanes[0].lane)
         if route is not None and not dispatch.composite:
             return dataclasses.replace(dispatch, cascade=route)
@@ -306,12 +310,16 @@ class OperatingPointPolicy(DispatchPolicy):
                 f"activity must be in [0, 1], got {activity}")
         self._activity[lane] = activity
 
-    def _choose(self, lane: str, pending: int, size: int,
-                spent: float, time: float) -> str:
+    def choose(self, lane: str, pending: int, size: int,
+               spent: Optional[float] = None,
+               time: Optional[float] = None) -> str:
         """Most accurate affordable variant for ``lane`` at dispatch size
-        ``size``, given committed totals ``(spent, time)``; backlog
-        pressure and quiet-scene activity each downshift one more step;
-        the cheapest variant is the unconditional floor."""
+        ``size``, given totals ``(spent, time)`` (by default the
+        committed ones); backlog pressure and quiet-scene activity each
+        downshift one more step; the cheapest variant is the
+        unconditional floor."""
+        spent = self.spent_uj if spent is None else spent
+        time = self.chip_time_s if time is None else time
         order = self._order[lane]
         idx = len(order) - 1                      # floor: cheapest
         for i, v in enumerate(order):
@@ -327,6 +335,12 @@ class OperatingPointPolicy(DispatchPolicy):
             idx = min(idx + 1, len(order) - 1)    # quiet-scene downshift
         return order[idx]
 
+    def commit(self, variant: str, slots: int) -> None:
+        """Commit the chip-model energy and time of ``slots`` frame slots
+        of ``variant`` against the budget."""
+        self.spent_uj += slots * self._e1[variant]
+        self.chip_time_s += slots * self._t1[variant]
+
     def select(self, queue: FrameQueue) -> Optional[Dispatch]:
         return self.select_sized(queue, self.ctx.batch)
 
@@ -336,23 +350,22 @@ class OperatingPointPolicy(DispatchPolicy):
         if lane is None:
             return None
         queue.advance_past(lane)
-        spent, time = self.spent_uj, self.chip_time_s
 
-        head = self._choose(lane, queue.pending(lane), size, spent, time)
+        head = self.choose(lane, queue.pending(lane), size)
         picks = [(lane, head)]
         occ = 1.0 / self.ctx.programs[head].s
-        spent += size * self._e1[head]
-        time += size * self._t1[head]
 
         if self.shared and occ < 1.0 - 1e-9 and lane not in self.cascades:
             # riders: other backlogged lanes whose chosen variants fill
             # the freed sub-array lanes — commit only on an exact tiling
+            spent = self.spent_uj + size * self._e1[head]
+            time = self.chip_time_s + size * self._t1[head]
             for other in queue.rr_lanes():
                 if (other == lane or not queue.pending(other)
                         or other in self.cascades):
                     continue
-                v = self._choose(other, queue.pending(other), size,
-                                 spent, time)
+                v = self.choose(other, queue.pending(other), size,
+                                spent, time)
                 w = 1.0 / self.ctx.programs[v].s
                 if occ + w > 1.0 + 1e-9:
                     continue
@@ -364,10 +377,9 @@ class OperatingPointPolicy(DispatchPolicy):
                     break
             if occ < 1.0 - 1e-9 and len(picks) > 1:
                 picks = picks[:1]                 # no exact tiling: solo
-                spent = self.spent_uj + size * self._e1[head]
-                time = self.chip_time_s + size * self._t1[head]
 
-        self.spent_uj, self.chip_time_s = spent, time
+        for _l, v in picks:
+            self.commit(v, size)
         lanes = tuple(LaneDispatch(l, v, tuple(queue.take(l, size)))
                       for l, v in picks)
         batch = None if size == self.ctx.batch else size

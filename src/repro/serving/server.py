@@ -18,6 +18,16 @@ package is the TPU analogue of that controller, split mechanism/policy:
 * :class:`ChipServer` (this module) — wires them together and keeps the
   books (served/padded/energy billing via ``energy.serve_report``).
 
+The books have one writer, :meth:`ChipServer.bill`: every dispatch kind
+bills through it, at the moment its slot count is known.  A solo or
+composite dispatch bills at launch (the energy is burned the moment the
+batch hits the array), a fused cascade when it finishes (the recognizer's
+slots are a count the kernel returns), a delta-gated dispatch
+(``serving/temporal.py``) at its step.  The neighbours read the books
+through :meth:`ChipServer.stats` and the read-only accessors
+(:meth:`lane_variants`, :meth:`lane_geometry`, :attr:`total_served`,
+:meth:`latency_s`).
+
 All pre-split behaviour is preserved: ``megakernel=True`` runs dispatches
 through the whole-network resident kernel, ``prefetch=k`` pipelines
 submission to depth k (default 1; 0 is synchronous), ``shared=True``
@@ -70,6 +80,8 @@ class ServeStats:
     p95_ms: float = 0.0               # over timestamped frames (0.0 when
     p99_ms: float = 0.0               # nothing was stamped)
     padding_ratio: float = 0.0        # burned slots / billed slots
+    billed: int = 0                   # frame slots run (served + padded,
+                                      # across all lanes)
 
     @property
     def total_served(self) -> int:
@@ -212,18 +224,7 @@ class ChipServer:
         self.failed = False                  # set by fail(); fleet skips us
         self.aborted_inflight = 0            # in-flight frames fail() dropped
         self._next_rid = 0
-        self._dispatches = 0
-        self._shared_dispatches = 0
-        self._util_sum = 0.0
-        self._served = {lane: 0 for lane in self._lanes}
-        self._padded = {lane: 0 for lane in self._lanes}
-        self._vserved = {name: 0 for name in self.programs}
-        self._vpadded = {name: 0 for name in self.programs}
-        self._billed = 0                     # frame slots launched (served
-                                             # + padded, across all lanes)
-        # per-frame latency books, one compact row per lane of a served
-        # dispatch: (dispatch, t_done, lane, variant, rids, t_submits)
-        self._done: List[tuple] = []
+        self.reset_stats()
 
     def _make_policy(self, policy, budget_uj_s) -> DispatchPolicy:
         if isinstance(policy, DispatchPolicy):
@@ -257,6 +258,20 @@ class ChipServer:
     @property
     def families(self) -> Dict[str, Tuple[str, ...]]:
         return dict(self._families)
+
+    def lane_variants(self, lane: str) -> Tuple[str, ...]:
+        """The resident variants that serve ``lane`` (one for a plain
+        program lane)."""
+        return self._lane_variants[lane]
+
+    def lane_geometry(self, lane: str) -> Tuple[int, int, int]:
+        """The ``(height, width, channels)`` of a frame ``lane`` accepts."""
+        return self._geom[lane]
+
+    @property
+    def total_served(self) -> int:
+        """Frames served over every lane since the last reset."""
+        return sum(self._served.values())
 
     # -- request side -------------------------------------------------------
 
@@ -318,40 +333,58 @@ class ChipServer:
 
     # -- dispatch side ------------------------------------------------------
 
+    def claim_dispatch(self) -> int:
+        """The id of a dispatch about to run (its spans' ``dispatch``);
+        ids count up from the last reset."""
+        index = self._next_dispatch
+        self._next_dispatch += 1
+        return index
+
+    def bill(self, rows: Sequence[Tuple[str, str, int, int]], *,
+             sequential: bool = False) -> None:
+        """Book one dispatch.  Each row ``(lane, variant, served, slots)``
+        says the array ran ``variant`` over ``slots`` frame slots, of
+        which ``served`` carried real frames of ``lane`` and the rest
+        were padding, so ``billed == served + padded`` by construction.
+        The dispatch's array occupancy is its variant's share of the
+        array for one row; for several, the slot-weighted mean when they
+        ran one after another (``sequential``: a fused cascade's detector
+        then recognizer), else the sum over the rows that carried frames
+        (a shared-array composite, whose members run side by side)."""
+        for lane, variant, served, slots in rows:
+            self._served[lane] += served
+            self._padded[lane] += slots - served
+            self._vserved[variant] += served
+            self._vpadded[variant] += slots - served
+            self._billed += slots
+        self._dispatches += 1
+        if len(rows) == 1:
+            self._util_sum += 1.0 / self.programs[rows[0][1]].s
+        elif sequential:
+            self._util_sum += (sum(k / self.programs[v].s
+                                   for _l, v, _n, k in rows)
+                               / sum(k for *_r, k in rows))
+        else:
+            self._shared_dispatches += 1
+            self._util_sum += energy.array_occupancy(
+                [self.programs[v] for _l, v, n, _k in rows if n])
+
     def _launch(self) -> Optional[Dict[str, Any]]:
-        """Consult the policy for the next dispatch, run it, and bill it.
-        Serving counters are billed at launch — the energy is burned the
-        moment the batch hits the array, synced or not — except for a
-        fused cascade, whose recognizer bill is a count the kernel
-        returns: it is billed when it finishes (:meth:`_book_cascade`)."""
-        index = self._dispatches
-        with self.probe.span("serve.select", dispatch=index):
+        """Consult the policy for the next dispatch, run it, and bill it
+        (a fused cascade is billed when it finishes)."""
+        with self.probe.span("serve.select", dispatch=self._next_dispatch):
             dispatch = self.policy.select(self.queue)
         if dispatch is None:
             return None
-        self._dispatches += 1
+        index = self.claim_dispatch()
         handle = self.executor.launch(dispatch, index)
         if dispatch.cascade is not None:
             return handle
         with self.probe.span("serve.finish", dispatch=index):
             size = (dispatch.batch if dispatch.batch is not None
                     else self.batch)
-            live = []
-            for ld in dispatch.lanes:
-                n = len(ld.requests)
-                self._served[ld.lane] += n
-                self._padded[ld.lane] += size - n
-                self._vserved[ld.variant] += n
-                self._vpadded[ld.variant] += size - n
-                self._billed += size
-                if n:
-                    live.append(self.programs[ld.variant])
-            if dispatch.composite:
-                self._shared_dispatches += 1
-                self._util_sum += energy.array_occupancy(live)
-            else:
-                self._util_sum += 1.0 / self.programs[
-                    dispatch.lanes[0].variant].s
+            self.bill([(ld.lane, ld.variant, len(ld.requests), size)
+                       for ld in dispatch.lanes])
         return handle
 
     def _book_cascade(self, dispatch: Dispatch, escalated: int,
@@ -362,30 +395,18 @@ class ChipServer:
         ld, = dispatch.lanes
         route = dispatch.cascade
         size = dispatch.batch if dispatch.batch is not None else self.batch
-        n = len(ld.requests)
-        for lane, variant, served, burned in (
-                (ld.lane, ld.variant, n, size - n),
-                (route.recognizer, route.rec_variant, escalated,
-                 slots - escalated)):
-            self._served[lane] += served
-            self._padded[lane] += burned
-            self._vserved[variant] += served
-            self._vpadded[variant] += burned
-        self._billed += size + slots
-        # sequential phases: slot-weighted mean of the two occupancies
-        sd = self.programs[ld.variant].s
-        sr = self.programs[route.rec_variant].s
-        self._util_sum += (size / sd + slots / sr) / (size + slots)
+        self.bill([(ld.lane, ld.variant, len(ld.requests), size),
+                   (route.recognizer, route.rec_variant, escalated, slots)],
+                  sequential=True)
 
     def step(self) -> List[FrameResult]:
         """One dispatch: pull a static batch, run its program(s), return
         results for the real (non-padding) frames.  [] once drained.
 
         With ``prefetch=k`` (k >= 1; the default is 1) up to k batches
-        are staged and dispatched *before* blocking on the oldest one,
-        and at k >= 2 finished results are pulled to the host by a
-        background thread; batches still leave the queue in exactly the
-        synchronous order, so fairness is untouched.  A step's results
+        are staged and dispatched *before* blocking on the oldest one;
+        batches still leave the queue in exactly the synchronous order,
+        so fairness is untouched.  A step's results
         are those of the dispatch it blocked on; the next one may
         already be in flight (:meth:`owed` counts it).
 
@@ -397,7 +418,7 @@ class ChipServer:
         dispatch to launch as the step began; ``host_wall_s`` is their
         sum.
         """
-        with self.probe.span("serve.step", dispatch=self._dispatches):
+        with self.probe.span("serve.step", dispatch=self._next_dispatch):
             results = self.executor.step(self._launch)
             if results:
                 with self.probe.span("serve.finish",
@@ -427,7 +448,7 @@ class ChipServer:
         sum of its ``serve.step`` spans)."""
         return self.probe.seconds.get("serve.step", 0.0)
 
-    def _latency_s(self) -> np.ndarray:
+    def latency_s(self) -> np.ndarray:
         """Input-to-label seconds of every stamped frame, completion
         order."""
         lats = [t_done - t_sub[t_sub > 0.0]
@@ -458,10 +479,9 @@ class ChipServer:
             self.policy.set_flush(False)
 
     def close(self) -> None:
-        """Release the background fetch thread, syncing (and discarding —
-        ``drain()`` first to collect them) any in-flight dispatches.  The
-        server keeps working afterwards with prefetch degraded to
-        synchronous fetch; safe to call more than once."""
+        """Sync (and discard — ``drain()`` first to collect them) any
+        in-flight dispatches.  The server keeps working afterwards; safe
+        to call more than once."""
         self.executor.close()
 
     def fail(self) -> Dict[str, List[FrameRequest]]:
@@ -495,7 +515,8 @@ class ChipServer:
         """Zero the serving counters and latency books, keeping all
         compiled state — benches warm the jit caches through the real
         serve path, then measure from a clean ledger."""
-        self._dispatches = 0
+        self._next_dispatch = 0              # id of the next dispatch
+        self._dispatches = 0                 # dispatches billed
         self._shared_dispatches = 0
         self._util_sum = 0.0
         self._served = {lane: 0 for lane in self._lanes}
@@ -503,17 +524,19 @@ class ChipServer:
         self._vserved = {name: 0 for name in self.programs}
         self._vpadded = {name: 0 for name in self.programs}
         self.probe.clear()
-        self._billed = 0
-        self._done = []
+        self._billed = 0                     # frame slots run (served
+                                             # + padded, across all lanes)
+        # per-frame latency books, one compact row per lane of a served
+        # dispatch: (dispatch, t_done, lane, variant, rids, t_submits)
+        self._done: List[tuple] = []
         for v in self.policy.variant_dispatches:
             self.policy.variant_dispatches[v] = 0
 
     def latency_trace(self) -> List[Dict[str, Any]]:
         """Per-frame admission-to-label records (stamped frames only), in
         completion order, built from the per-dispatch rows when read.
-        Read by ``ServeFleet.latency_trace`` (merged over replicas), by
-        ``launch/chip_serve.py`` (the share within the SLO) and by
-        ``benchmarks/kernel_microbench.py`` (its latency trace file)."""
+        Read by ``ServeFleet.latency_trace`` (merged over replicas) and
+        by ``launch/chip_serve.py`` (the share within the SLO)."""
         out: List[Dict[str, Any]] = []
         for disp, t_done, lane, variant, rids, t_sub in self._done:
             if t_done <= 0.0:
@@ -540,7 +563,7 @@ class ChipServer:
             for v in self.programs)
         budget = getattr(self.policy, "budget_uj_s", None)
         vd = dict(self.policy.variant_dispatches)
-        lats = self._latency_s()
+        lats = self.latency_s()
         if len(lats):
             p50, p95, p99 = np.percentile(lats, [50, 95, 99])
         else:
@@ -563,4 +586,5 @@ class ChipServer:
                           p50_ms=float(p50) * 1e3,
                           p95_ms=float(p95) * 1e3,
                           p99_ms=float(p99) * 1e3,
-                          padding_ratio=ratio)
+                          padding_ratio=ratio,
+                          billed=self._billed)

@@ -22,9 +22,12 @@ serving tier, split by what the chip actually ran:
 
 * the **server ledger** bills full-network inferences only — the slots
   the kernel's change queue drained (changed streams + drain-chunk
-  padding, from the kernel's own scalar report).  ``billed == served +
-  padded`` still holds per lane; skipped frames never hit the array and
-  never appear in it.
+  padding, from the kernel's own scalar report), through
+  :meth:`ChipServer.bill` at the dispatch's step, like every other
+  dispatch kind.  ``billed == served + padded`` still holds per lane;
+  skipped frames never hit the array and never appear in it.  Under an
+  operating-point policy the same slots are committed against the
+  energy budget (:meth:`OperatingPointPolicy.commit`).
 * the **pipeline ledger** (:meth:`TemporalPipeline.report` ->
   :func:`energy.temporal_report`) bills every frame the delta-compute
   toll (one IO pass: the frame must stream in to be compared) and adds
@@ -246,7 +249,7 @@ class TemporalPipeline:
         if not 0.0 < activity_alpha <= 1.0:
             raise ValueError(
                 f"activity_alpha must be in (0, 1], got {activity_alpha}")
-        self.variants = server._lane_variants[lane]
+        self.variants = server.lane_variants(lane)
         if len(self.variants) > 1 and not isinstance(
                 server.policy, OperatingPointPolicy):
             raise ValueError(
@@ -310,8 +313,8 @@ class TemporalPipeline:
             return self._variant
         pol = self.server.policy
         pol.set_activity(self.lane, self._activity)
-        variant = pol._choose(self.lane, self.server.queue.pending(self.lane),
-                              size, pol.spent_uj, pol.chip_time_s)
+        variant = pol.choose(self.lane, self.server.queue.pending(self.lane),
+                             size)
         if variant != self._variant:
             self._state.pop(variant, None)        # cold-start the newcomer
             self._variant = variant
@@ -323,18 +326,19 @@ class TemporalPipeline:
         cached answer from the same kernel).  The dispatch is the
         server's ``serve.step`` span."""
         srv = self.server
-        with srv.probe.span("serve.step", dispatch=srv._dispatches):
-            return self._run_gated(reqs)
+        index = srv.claim_dispatch()
+        with srv.probe.span("serve.step", dispatch=index):
+            return self._run_gated(reqs, index)
 
-    def _run_gated(self, reqs) -> List[TemporalResult]:
+    def _run_gated(self, reqs, index: int) -> List[TemporalResult]:
         srv = self.server
         size = srv.batch
         n = len(reqs)
         variant = self._pick_variant(size)
         unit = srv.executor.delta_for(variant, rb=self.rb,
                                       check_every=self.check_every)
-        frames = srv.executor.pad_frames(reqs, srv._geom[self.lane], size,
-                                         srv._dispatches)
+        frames = srv.executor.pad_frames(reqs, srv.lane_geometry(self.lane),
+                                         size, index)
         state = self._state.get(variant)
         if state is None:
             last, llog = unit["plan"].init_state(size)
@@ -349,26 +353,17 @@ class TemporalPipeline:
         queue, counts = np.asarray(queue), np.asarray(counts)
         deltas = np.asarray(deltas)
         changed, slots = int(counts[0]), int(counts[1])
-        # bill at launch like ChipServer._launch, but only what the chip
-        # ran the network on: the slots the change queue drained (changed
-        # streams + drain-chunk padding, from the kernel's own report).
-        # Skipped frames never hit the array; their delta-compute toll is
-        # billed in report() via energy.temporal_report.
-        srv._served[self.lane] += changed
-        srv._padded[self.lane] += slots - changed
-        srv._vserved[variant] += changed
-        srv._vpadded[variant] += slots - changed
-        srv._billed += slots
-        srv._dispatches += 1
-        srv._util_sum += 1.0 / srv.programs[variant].s
+        # bill only what the chip ran the network on: the slots the change
+        # queue drained (changed streams + drain-chunk padding, from the
+        # kernel's own report).  Skipped frames never hit the array; their
+        # delta-compute toll is billed in report() via
+        # energy.temporal_report.  The budget is committed for the same
+        # slots: the gate's savings are real savings against it.
+        srv.bill([(self.lane, variant, changed, slots)])
         pol = srv.policy
-        pol.variant_dispatches[variant] = (
-            pol.variant_dispatches.get(variant, 0) + 1)
+        pol.count_variant(variant)
         if isinstance(pol, OperatingPointPolicy):
-            # commit budget spend for the computed slots only — the gate's
-            # savings are real savings against the energy budget
-            pol.spent_uj += slots * pol._e1[variant]
-            pol.chip_time_s += slots * pol._t1[variant]
+            pol.commit(variant, slots)
         self.gated_dispatches += 1
         self._frames_total += n
         self._computed += changed
@@ -452,12 +447,12 @@ class TemporalPipeline:
         """Calibrate ``self.threshold`` on a held-out video trace via
         :func:`calibrate_delta_threshold` (the pipeline's own current
         operating point); returns — and adopts — the chosen threshold."""
-        ex = self.server.executor
+        artifact, interpret = self.server.executor.calibration_inputs(
+            self._variant)
         self.threshold = calibrate_delta_threshold(
             frames, target_agreement,
             program=self.server.programs[self._variant],
-            artifact=ex._raw_artifacts[self._variant],
-            interpret=ex._interpret)
+            artifact=artifact, interpret=interpret)
         return self.threshold
 
     def report(self) -> energy.TemporalReport:

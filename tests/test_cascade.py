@@ -157,8 +157,8 @@ def test_cascade_billing_ragged_drain(cascade_setup):
     casc.submit_many(frames)
     casc.drain()
     stats = server.stats()
-    assert server._billed == (sum(stats.served.values())
-                              + sum(stats.padded.values()))
+    assert stats.billed == (sum(stats.served.values())
+                            + sum(stats.padded.values()))
     assert stats.served["det"] == 7 and stats.padded["det"] == 1
     assert stats.served["rec"] == 3 and stats.padded["rec"] == 1
     rep = casc.report()

@@ -19,6 +19,7 @@ Locks down the serving subsystem three ways:
 """
 
 import random
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -198,8 +199,9 @@ def test_prefetch_interleaved_with_submission(mnist_setup):
 
 def test_prefetch_depth_k_serves_identical_results(multi_setup):
     """prefetch=k for any depth (incl. deeper than the queue) returns the
-    exact synchronous result stream — depth-k pipelining with async host
-    fetch is pure overlap, dispatch order and billing never change."""
+    exact synchronous result stream — depth-k pipelining is pure overlap,
+    dispatch order and billing never change — and runs no thread of its
+    own: no ``serve-fetch`` thread exists at any depth."""
     progs, arts = multi_setup
     frames = {n: _frames(p, 7, seed=40 + i)
               for i, (n, p) in enumerate(progs.items())}
@@ -210,7 +212,10 @@ def test_prefetch_depth_k_serves_identical_results(multi_setup):
         for i in range(7):
             for n in progs:
                 server.submit(n, frames[n][i])
-        results = server.drain()
+        results = server.step()
+        assert not [t for t in threading.enumerate()
+                    if t.name.startswith("serve-fetch")], depth
+        results += server.drain()
         stats = server.stats()
         runs[depth] = ([(r.rid, r.program, r.label, r.dispatch)
                         for r in results],

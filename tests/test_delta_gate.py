@@ -413,13 +413,13 @@ def test_activity_downshifts_quiet_scene():
                      policy="operating-point")
     pol = srv.policy
     order = pol.variant_order("cifar10")
-    busy = pol._choose("cifar10", 0, 2, 0.0, 0.0)
+    busy = pol.choose("cifar10", 0, 2)
     assert busy == order[0]
     pol.set_activity("cifar10", 0.1)        # below activity_low
-    quiet = pol._choose("cifar10", 0, 2, 0.0, 0.0)
+    quiet = pol.choose("cifar10", 0, 2)
     assert quiet == order[1]
     pol.set_activity("cifar10", 0.9)        # active again: back to the top
-    assert pol._choose("cifar10", 0, 2, 0.0, 0.0) == order[0]
+    assert pol.choose("cifar10", 0, 2) == order[0]
     with pytest.raises(KeyError):
         pol.set_activity("nope", 0.5)
     with pytest.raises(ValueError):
